@@ -12,8 +12,10 @@ The bench reproduces that by sampling feasible random specs per round with
 a fresh seed (the tiny fixed fleet would otherwise let the GA converge and
 hide the recompile cost that motivates the engine).
 
-Each (family × mode × cohort size) leg runs in its own subprocess so jit
-caches are cold, as they are for a real server process. Wall-clock per
+Each (family × mode × cohort size) leg runs in this process after
+``jax.clear_caches()``, so its compiles are cold, as they are for a fresh
+server process; no leg starts a child process (an accelerator belongs to
+the one process that first touched JAX). Wall-clock per
 round covers local training + eval + aggregation, including any compiles
 it triggers; submodel search / predictor updates are identical in both
 modes and excluded. Rows carry JSON derived fields (benchmarks.common)
@@ -60,8 +62,6 @@ import argparse
 import json
 import os
 import random
-import subprocess
-import sys
 import time
 from typing import List
 
@@ -228,25 +228,12 @@ def _measure_leg_transformer(mode: str, n_workers: int, seed: int = 0):
 MEASURE = {"cnn": _measure_leg_cnn, "transformer": _measure_leg_transformer}
 
 
-def _run_leg_subprocess(family: str, mode: str, n_workers: int):
-    env = dict(os.environ)
-    src = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.round_engine", "--single",
-         family, mode, str(n_workers)],
-        capture_output=True, text=True, env=env,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    if out.returncode != 0:
-        raise RuntimeError(
-            f"{family}/{mode}/{n_workers}c leg failed:\n{out.stderr}")
-    for line in out.stdout.splitlines():
-        if line.startswith("LEG,"):
-            rec = json.loads(line[len("LEG,"):])
-            return (rec["walls"], rec["compiles"], rec["nspecs"],
-                    rec.get("kernel_path", "dense-masked"))
-    raise RuntimeError(f"no LEG line in output:\n{out.stdout}")
+def _run_leg(family: str, mode: str, n_workers: int):
+    """One leg in this process with every jit cache cleared first, so the
+    leg pays its compiles cold."""
+    import jax
+    jax.clear_caches()
+    return MEASURE[family](mode, n_workers)
 
 
 def run(seed: int = 0) -> List[Row]:
@@ -255,7 +242,7 @@ def run(seed: int = 0) -> List[Row]:
     for family, sweep in SWEEP.items():
         for n_workers in sweep:
             for mode in ("seq", "batched"):
-                walls, compiles, nspecs, kernel_path = _run_leg_subprocess(
+                walls, compiles, nspecs, kernel_path = _run_leg(
                     family, mode, n_workers)
                 per_round = float(np.mean(walls))
                 summary[(family, n_workers, mode)] = (per_round, compiles)

@@ -1,7 +1,8 @@
 """Config registry: ``get_config(arch_id)`` resolves any assigned arch."""
 from repro.configs.base import (INPUT_SHAPES, InputShape, MLAConfig,
                                 ModelConfig, MoEConfig, Segment, SSMConfig,
-                                flops_per_token, reduced, uniform_segments)
+                                depth_cut, flops_per_token, reduced,
+                                uniform_segments)
 from repro.configs.archs import ARCHS, supported_pairs
 from repro.configs.paper_cnn import PAPER_CNN, CNNConfig
 
@@ -16,7 +17,7 @@ def get_config(arch_id: str) -> ModelConfig:
 
 __all__ = [
     "ARCHS", "INPUT_SHAPES", "InputShape", "MLAConfig", "ModelConfig",
-    "MoEConfig", "Segment", "SSMConfig", "get_config", "reduced",
+    "MoEConfig", "Segment", "SSMConfig", "depth_cut", "get_config", "reduced",
     "uniform_segments", "supported_pairs", "flops_per_token", "PAPER_CNN",
     "CNNConfig",
 ]

@@ -320,6 +320,27 @@ def reduced(cfg: ModelConfig, *, n_layers: int = 2, d_model: int = 256,
     )
 
 
+def depth_cut(cfg: ModelConfig, n_layers: int) -> ModelConfig:
+    """Published widths, depth cut to the first ``n_layers`` layers.
+
+    Segments keep their order and kind; the last one kept is shortened
+    (an ``attn_pair`` segment counts two layers per scan step and keeps at
+    least one pair). Every width — d_model, heads, head_dim, d_ff, vocab,
+    experts, SSM state — is the config's own."""
+    segs, left = [], int(n_layers)
+    for seg in cfg.segments:
+        if left <= 0:
+            break
+        per = 2 if seg.kind == "attn_pair" else 1
+        take = min(seg.n_layers, max(1, left // per))
+        segs.append(dataclasses.replace(seg, n_layers=take))
+        left -= take * per
+    depth = sum(s.n_layers * (2 if s.kind == "attn_pair" else 1)
+                for s in segs)
+    return dataclasses.replace(cfg, name=f"{cfg.name}-{depth}L",
+                               n_layers=depth, segments=tuple(segs))
+
+
 def flops_per_token(cfg: ModelConfig, seq_len: int) -> float:
     """Rough fwd FLOPs/token: 2*active_params + attention term."""
     base = 2.0 * cfg.active_param_count()

@@ -256,7 +256,6 @@ def _hierarchical_program(mesh, coverage_norm: bool, has_participation: bool,
     becomes one explicit collective, which is the shape that scales to
     the multi-host fleet (ROADMAP item 1).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     rep, sh = P(), P("cohort")
 
@@ -280,8 +279,8 @@ def _hierarchical_program(mesh, coverage_norm: bool, has_participation: bool,
         return jax.tree.map(lambda p, d: (p - d).astype(p.dtype), params,
                             delta_t)
 
-    inner = shard_map(local, mesh=mesh, in_specs=(rep, sh, sh, sh),
-                      out_specs=rep)
+    inner = jax.shard_map(local, mesh=mesh, in_specs=(rep, sh, sh, sh),
+                          out_specs=rep)
 
     def run(params, stacked_deltas, stacked_coverages, weights,
             participation):

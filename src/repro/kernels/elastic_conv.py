@@ -50,7 +50,7 @@ def _im2col(x, kh: int, kw: int, stride: int):
 
 
 def elastic_conv2d(x, w, b=None, *, stride: int = 1, cin_active=None,
-                   cout_active=None, interpret: bool = True,
+                   cout_active=None, interpret: bool | None = None,
                    bm: int = 128, bn: int = 128, bk: int = 128):
     """Tile-skipping SAME conv. x: (B,H,W,Cin); w: (kh,kw,Cin,Cout);
     b: (Cout,) fused bias; cin_active / cout_active: runtime int32 channel

@@ -50,9 +50,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from repro.kernels.backend import default_interpret
 
 # the single activation table — fused acts must match the dense paths
 from repro.models.layers import ACTIVATIONS as _ACTS  # noqa: E402
@@ -191,7 +189,7 @@ def _edense_call(x, w, bias, ka, na, ma, *, act, bm, bn, bk, interpret):
                           act=act, has_bias=has_bias),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(scalars, *args)
@@ -270,7 +268,7 @@ def _make_edense(act, has_bias, bm, bn, bk, interpret):
 
 def elastic_dense(x, w, bias=None, *, k_active=None, n_active=None,
                   m_active=None, act=None, bm=128, bn=128, bk=128,
-                  interpret=True):
+                  interpret=None):
     """Differentiable tile-skipping dense layer.
 
     ``y = act((x ⊙ [k < k_active]) @ w + bias) ⊙ [n < n_active]
@@ -286,7 +284,7 @@ def elastic_dense(x, w, bias=None, *, k_active=None, n_active=None,
     na = jnp.asarray(N if n_active is None else n_active, jnp.int32)
     ma = jnp.asarray(M if m_active is None else m_active, jnp.int32)
     f = _make_edense(act, bias is not None, int(bm), int(bn), int(bk),
-                     bool(interpret))
+                     default_interpret(interpret))
     if bias is None:
         y = f(x2, w, ka, na, ma)
     else:
@@ -300,7 +298,7 @@ def elastic_dense(x, w, bias=None, *, k_active=None, n_active=None,
 @functools.partial(jax.jit,
                    static_argnames=("bm", "bn", "bk", "interpret"))
 def elastic_matmul(x, w, k_active, *, bm=128, bn=128, bk=128,
-                   interpret=True):
+                   interpret=None):
     """y[m, n] = sum_k x[m,k] w[k,n] for n < k_active else 0.
 
     x: (M, K), w: (K, N), k_active: int32 scalar (dynamic). Kept with the
@@ -310,4 +308,5 @@ def elastic_matmul(x, w, k_active, *, bm=128, bn=128, bk=128,
     return _edense_call(x, w, None, jnp.asarray(x.shape[-1], jnp.int32),
                         jnp.asarray(k_active, jnp.int32),
                         jnp.asarray(x.shape[0], jnp.int32),
-                        act=None, bm=bm, bn=bn, bk=bk, interpret=interpret)
+                        act=None, bm=bm, bn=bn, bk=bk,
+                        interpret=default_interpret(interpret))

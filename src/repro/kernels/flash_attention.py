@@ -27,6 +27,12 @@ innermost, per-head dk/dv accumulators; the host group-sums the H-sized
 result onto the KV heads). Both rebuild the score tile from the saved
 lse and ``delta = Σ_d do·o``, flash-v2 style.
 
+Layout: the kernels run head-major — q/k/v/o as (B, H, S, D), lse and
+delta as (B, H, 1, S) rows — so every block's last two dims are a
+(seq-block, D) or (1, seq-block) tile that the TPU lowering accepts (a
+block of one head out of a (.., S, H, D) array is not). The public
+(B, S, H, D) layout is transposed at the custom-vjp boundary.
+
 A subtlety the forward guards against: a row can be *fully masked inside
 a contributing block* (``bk < bq`` under causal, or a sliding-window
 block edge). Its running max then stays NEG_INF and ``exp(s - m)`` would
@@ -45,10 +51,6 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from repro.kernels.backend import default_interpret
-
-# jax renamed TPUCompilerParams -> CompilerParams across releases
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
 
 NEG_INF = -2.0 ** 30
 
@@ -129,9 +131,9 @@ def _fwd_kernel(s_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         @pl.when(_contributes(qi, ki, bq=bq, bk=bk, causal=causal,
                               window=window))
         def _step():
-            q = q_ref[0, :, 0, :].astype(jnp.float32)
-            k = k_ref[0, :, 0, :].astype(jnp.float32)
-            v = v_ref[0, :, 0, :].astype(jnp.float32)
+            q = q_ref[...].astype(jnp.float32)
+            k = k_ref[...].astype(jnp.float32)
+            v = v_ref[...].astype(jnp.float32)
             s, mask, _ = _masked_scores(q, k, q0, k0, bq, bk, causal,
                                         window, cap, scale)
             s = jnp.where(mask, s, NEG_INF)
@@ -150,12 +152,12 @@ def _fwd_kernel(s_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         @pl.when(ki == nk - 1)
         def _write():
             l = l_ref[...]
-            o_ref[0, :, 0, :] = (acc_ref[...] /
-                                 jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-            lse_ref[0, 0, :] = jnp.where(
-                l[:, 0] > 0.0, m_ref[:, 0] + jnp.log(jnp.maximum(l[:, 0],
-                                                                 1e-30)),
-                NEG_INF).astype(lse_ref.dtype)
+            o_ref[...] = (acc_ref[...] /
+                          jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+            lse = jnp.where(l > 0.0,
+                            m_ref[...] + jnp.log(jnp.maximum(l, 1e-30)),
+                            NEG_INF)                     # (bq, 1) column
+            lse_ref[...] = lse.T.astype(lse_ref.dtype)
 
 
 def _kv_block_range(*, bq, bk, nk, causal, window):
@@ -197,21 +199,28 @@ def attn_fwd_index_maps(H, G, *, bq, bk, nk, causal, window):
         return jax.lax.rem(bh, H) < s[0]
 
     def qm(bh, qi, ki, s):
-        return (bh // H, jnp.where(live(bh, s), qi, 0), hcl(bh, s), 0)
+        return (bh // H, hcl(bh, s), jnp.where(live(bh, s), qi, 0), 0)
 
     def km(bh, qi, ki, s):
         lo, hi = krng(qi)
         kc = jnp.clip(ki, lo, hi)
-        return (bh // H, jnp.where(live(bh, s), kc, 0),
-                hcl(bh, s) // G, 0)
+        return (bh // H, hcl(bh, s) // G,
+                jnp.where(live(bh, s), kc, 0), 0)
 
     return [qm, km, km]
 
 
+def _hm(x):
+    """(B, S, H, D) <-> (B, H, S, D)."""
+    return jnp.swapaxes(x, 1, 2)
+
+
 def _fwd_call(q, k, v, ha, *, causal, window, cap, scale, bq, bk,
               interpret):
-    B, Sq, H, D = q.shape
-    Sk, KV = k.shape[1], k.shape[2]
+    """Head-major forward. q: (B,H,Sq,D) k,v: (B,KV,Sk,D) ->
+    o (B,H,Sq,D), lse (B,H,1,Sq)."""
+    B, H, Sq, D = q.shape
+    KV, Sk = k.shape[1], k.shape[2]
     G = H // KV
     nk = Sk // bk
     grid = (B * H, Sq // bq, nk)
@@ -221,15 +230,15 @@ def _fwd_call(q, k, v, ha, *, causal, window, cap, scale, bq, bk,
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, 1, D), maps[0]),
-            pl.BlockSpec((1, bk, 1, D), maps[1]),
-            pl.BlockSpec((1, bk, 1, D), maps[2]),
+            pl.BlockSpec((None, None, bq, D), maps[0]),
+            pl.BlockSpec((None, None, bk, D), maps[1]),
+            pl.BlockSpec((None, None, bk, D), maps[2]),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, 1, D),
-                         lambda bh, qi, ki, s: (bh // H, qi, bh % H, 0)),
-            pl.BlockSpec((1, 1, bq),
-                         lambda bh, qi, ki, s: (bh // H, bh % H, qi)),
+            pl.BlockSpec((None, None, bq, D),
+                         lambda bh, qi, ki, s: (bh // H, bh % H, qi, 0)),
+            pl.BlockSpec((None, None, 1, bq),
+                         lambda bh, qi, ki, s: (bh // H, bh % H, 0, qi)),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
@@ -243,9 +252,9 @@ def _fwd_call(q, k, v, ha, *, causal, window, cap, scale, bq, bk,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1, Sq), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(ha, q, k, v)
@@ -254,17 +263,18 @@ def _fwd_call(q, k, v, ha, *, causal, window, cap, scale, bq, bk,
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
-def _bwd_tile(q, k, v, do, lse_row, delta_row, q0, k0, *,
+def _bwd_tile(q, k, v, do, lse_ref, d_ref, q0, k0, *,
               bq, bk, causal, window, cap, scale):
-    """Rebuild p from lse and return (p, ds) for one (bq, bk) tile."""
+    """Rebuild p from lse and return (p, ds) for one (bq, bk) tile.
+    ``lse_ref``/``d_ref`` hold (1, bq) rows; they are used as columns."""
     s, mask, dcap = _masked_scores(q, k, q0, k0, bq, bk, causal, window,
                                    cap, scale)
-    live_row = lse_row > NEG_INF * 0.5                 # (bq,)
-    p = jnp.where(mask & live_row[:, None],
-                  jnp.exp(s - lse_row[:, None]), 0.0)
+    lse = lse_ref[...].T                               # (bq, 1)
+    delta = d_ref[...].T                               # (bq, 1)
+    p = jnp.where(mask & (lse > NEG_INF * 0.5), jnp.exp(s - lse), 0.0)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_row[:, None])
+    ds = p * (dp - delta)
     if dcap is not None:
         ds = ds * dcap
     return p, ds * scale
@@ -290,11 +300,11 @@ def _dq_kernel(s_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
         @pl.when(_contributes(qi, ki, bq=bq, bk=bk, causal=causal,
                               window=window))
         def _step():
-            q = q_ref[0, :, 0, :].astype(jnp.float32)
-            k = k_ref[0, :, 0, :].astype(jnp.float32)
-            v = v_ref[0, :, 0, :].astype(jnp.float32)
-            do = do_ref[0, :, 0, :].astype(jnp.float32)
-            _, ds = _bwd_tile(q, k, v, do, lse_ref[0, 0, :], d_ref[0, 0, :],
+            q = q_ref[...].astype(jnp.float32)
+            k = k_ref[...].astype(jnp.float32)
+            v = v_ref[...].astype(jnp.float32)
+            do = do_ref[...].astype(jnp.float32)
+            _, ds = _bwd_tile(q, k, v, do, lse_ref, d_ref,
                               qi * bq, ki * bk, bq=bq, bk=bk, causal=causal,
                               window=window, cap=cap, scale=scale)
             dq_acc[...] += jax.lax.dot_general(
@@ -303,7 +313,7 @@ def _dq_kernel(s_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
 
         @pl.when(ki == nk - 1)
         def _write():
-            dq_ref[0, :, 0, :] = dq_acc[...].astype(dq_ref.dtype)
+            dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(s_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
@@ -328,12 +338,12 @@ def _dkv_kernel(s_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
         @pl.when(_contributes(qi, ki, bq=bq, bk=bk, causal=causal,
                               window=window))
         def _step():
-            q = q_ref[0, :, 0, :].astype(jnp.float32)
-            k = k_ref[0, :, 0, :].astype(jnp.float32)
-            v = v_ref[0, :, 0, :].astype(jnp.float32)
-            do = do_ref[0, :, 0, :].astype(jnp.float32)
-            p, ds = _bwd_tile(q, k, v, do, lse_ref[0, 0, :],
-                              d_ref[0, 0, :], qi * bq, ki * bk,
+            q = q_ref[...].astype(jnp.float32)
+            k = k_ref[...].astype(jnp.float32)
+            v = v_ref[...].astype(jnp.float32)
+            do = do_ref[...].astype(jnp.float32)
+            p, ds = _bwd_tile(q, k, v, do, lse_ref, d_ref,
+                              qi * bq, ki * bk,
                               bq=bq, bk=bk, causal=causal, window=window,
                               cap=cap, scale=scale)
             dv_acc[...] += jax.lax.dot_general(
@@ -345,8 +355,8 @@ def _dkv_kernel(s_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
 
         @pl.when(qi == nq - 1)
         def _write():
-            dk_ref[0, :, 0, :] = dk_acc[...].astype(dk_ref.dtype)
-            dv_ref[0, :, 0, :] = dv_acc[...].astype(dv_ref.dtype)
+            dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+            dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def attn_dq_index_maps(H, G, *, bq, bk, nk, causal, window):
@@ -360,16 +370,16 @@ def attn_dq_index_maps(H, G, *, bq, bk, nk, causal, window):
         return jax.lax.rem(bh, H) < s[0]
 
     def qm(bh, qi, ki, s):
-        return (bh // H, jnp.where(live(bh, s), qi, 0), hcl(bh, s), 0)
+        return (bh // H, hcl(bh, s), jnp.where(live(bh, s), qi, 0), 0)
 
     def km(bh, qi, ki, s):
         lo, hi = krng(qi)
         kc = jnp.clip(ki, lo, hi)
-        return (bh // H, jnp.where(live(bh, s), kc, 0),
-                hcl(bh, s) // G, 0)
+        return (bh // H, hcl(bh, s) // G,
+                jnp.where(live(bh, s), kc, 0), 0)
 
     def lm(bh, qi, ki, s):
-        return (bh // H, hcl(bh, s), jnp.where(live(bh, s), qi, 0))
+        return (bh // H, hcl(bh, s), 0, jnp.where(live(bh, s), qi, 0))
 
     return [qm, km, km, qm, lm, lm]
 
@@ -390,38 +400,40 @@ def attn_dkv_index_maps(H, G, *, bq, bk, nq, causal, window):
         return jnp.where(live(bh, s), jnp.clip(qi, lo, hi), 0)
 
     def qm(bh, ki, qi, s):
-        return (bh // H, qc(bh, ki, qi, s), hcl(bh, s), 0)
+        return (bh // H, hcl(bh, s), qc(bh, ki, qi, s), 0)
 
     def km(bh, ki, qi, s):
-        return (bh // H, jnp.where(live(bh, s), ki, 0),
-                hcl(bh, s) // G, 0)
+        return (bh // H, hcl(bh, s) // G,
+                jnp.where(live(bh, s), ki, 0), 0)
 
     def lm(bh, ki, qi, s):
-        return (bh // H, hcl(bh, s), qc(bh, ki, qi, s))
+        return (bh // H, hcl(bh, s), 0, qc(bh, ki, qi, s))
 
     return [qm, km, km, qm, lm, lm]
 
 
 def _bwd_call(q, k, v, do, o, lse, ha, *, causal, window, cap, scale,
               bq, bk, interpret):
-    B, Sq, H, D = q.shape
-    Sk, KV = k.shape[1], k.shape[2]
+    """Head-major backward: (q, do, o) (B,H,Sq,D), (k, v) (B,KV,Sk,D),
+    lse (B,H,1,Sq) -> (dq, dk, dv) in the same layouts."""
+    B, H, Sq, D = q.shape
+    KV, Sk = k.shape[1], k.shape[2]
     G = H // KV
     nq, nk = Sq // bq, Sk // bk
-    delta = jnp.einsum("bshd,bshd->bhs", do.astype(jnp.float32),
-                       o.astype(jnp.float32))
+    delta = jnp.einsum("bhsd,bhsd->bhs", do.astype(jnp.float32),
+                       o.astype(jnp.float32))[:, :, None, :]
 
     common = dict(causal=causal, window=window, cap=cap, scale=scale,
                   n_heads=H)
     maps = attn_dq_index_maps(H, G, bq=bq, bk=bk, nk=nk, causal=causal,
                               window=window)
     in_specs = [
-        pl.BlockSpec((1, bq, 1, D), maps[0]),
-        pl.BlockSpec((1, bk, 1, D), maps[1]),
-        pl.BlockSpec((1, bk, 1, D), maps[2]),
-        pl.BlockSpec((1, bq, 1, D), maps[3]),
-        pl.BlockSpec((1, 1, bq), maps[4]),
-        pl.BlockSpec((1, 1, bq), maps[5]),
+        pl.BlockSpec((None, None, bq, D), maps[0]),
+        pl.BlockSpec((None, None, bk, D), maps[1]),
+        pl.BlockSpec((None, None, bk, D), maps[2]),
+        pl.BlockSpec((None, None, bq, D), maps[3]),
+        pl.BlockSpec((None, None, 1, bq), maps[4]),
+        pl.BlockSpec((None, None, 1, bq), maps[5]),
     ]
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, bq=bq, bk=bk, nk=nk, **common),
@@ -430,48 +442,48 @@ def _bwd_call(q, k, v, do, o, lse, ha, *, causal, window, cap, scale,
             grid=(B * H, nq, nk),
             in_specs=in_specs,
             out_specs=pl.BlockSpec(
-                (1, bq, 1, D),
-                lambda bh, qi, ki, s: (bh // H, qi, bh % H, 0)),
+                (None, None, bq, D),
+                lambda bh, qi, ki, s: (bh // H, bh % H, qi, 0)),
             scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(ha, q, k, v, do, lse, delta)
 
     kmaps = attn_dkv_index_maps(H, G, bq=bq, bk=bk, nq=nq, causal=causal,
                                 window=window)
-    kv_out = lambda bh, ki, qi, s: (bh // H, ki, bh % H, 0)
+    kv_out = lambda bh, ki, qi, s: (bh // H, bh % H, ki, 0)
     dkf, dvf = pl.pallas_call(
         functools.partial(_dkv_kernel, bq=bq, bk=bk, nq=nq, **common),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B * H, nk, nq),
             in_specs=[
-                pl.BlockSpec((1, bq, 1, D), kmaps[0]),
-                pl.BlockSpec((1, bk, 1, D), kmaps[1]),
-                pl.BlockSpec((1, bk, 1, D), kmaps[2]),
-                pl.BlockSpec((1, bq, 1, D), kmaps[3]),
-                pl.BlockSpec((1, 1, bq), kmaps[4]),
-                pl.BlockSpec((1, 1, bq), kmaps[5]),
+                pl.BlockSpec((None, None, bq, D), kmaps[0]),
+                pl.BlockSpec((None, None, bk, D), kmaps[1]),
+                pl.BlockSpec((None, None, bk, D), kmaps[2]),
+                pl.BlockSpec((None, None, bq, D), kmaps[3]),
+                pl.BlockSpec((None, None, 1, bq), kmaps[4]),
+                pl.BlockSpec((None, None, 1, bq), kmaps[5]),
             ],
-            out_specs=[pl.BlockSpec((1, bk, 1, D), kv_out),
-                       pl.BlockSpec((1, bk, 1, D), kv_out)],
+            out_specs=[pl.BlockSpec((None, None, bk, D), kv_out),
+                       pl.BlockSpec((None, None, bk, D), kv_out)],
             scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                             pltpu.VMEM((bk, D), jnp.float32)],
         ),
-        out_shape=[jax.ShapeDtypeStruct((B, Sk, H, D), k.dtype),
-                   jax.ShapeDtypeStruct((B, Sk, H, D), v.dtype)],
-        compiler_params=_CompilerParams(
+        out_shape=[jax.ShapeDtypeStruct((B, H, Sk, D), k.dtype),
+                   jax.ShapeDtypeStruct((B, H, Sk, D), v.dtype)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(ha, q, k, v, do, lse, delta)
     # GQA: every query head wrote its own dk/dv; sum the groups back onto
     # the KV heads (skipped heads wrote zeros, so the prefix is free).
     if G != 1:
-        dkf = dkf.reshape(B, Sk, KV, G, D).sum(axis=3)
-        dvf = dvf.reshape(B, Sk, KV, G, D).sum(axis=3)
+        dkf = dkf.reshape(B, KV, G, Sk, D).sum(axis=2)
+        dvf = dvf.reshape(B, KV, G, Sk, D).sum(axis=2)
     return dq, dkf.astype(k.dtype), dvf.astype(v.dtype)
 
 
@@ -493,15 +505,21 @@ def _make_flash(causal, window, cap, scale, bq, bk, interpret, has_mask):
             return jnp.asarray(H, jnp.int32).reshape(1)
         return _active_len(head_mask).reshape(1)
 
+    def _out(q, k, v, head_mask):
+        ha = _ha(head_mask, q.shape[2])
+        return _hm(_fwd_call(_hm(q), _hm(k), _hm(v), ha, **kw)[0])
+
     def _grads(q, k, v, head_mask, dy):
         ha = _ha(head_mask, q.shape[2])
-        o, lse = _fwd_call(q, k, v, ha, **kw)
-        return _bwd_call(q, k, v, dy, o, lse, ha, **kw)
+        qh, kh, vh = _hm(q), _hm(k), _hm(v)
+        o, lse = _fwd_call(qh, kh, vh, ha, **kw)
+        return tuple(_hm(g) for g in
+                     _bwd_call(qh, kh, vh, _hm(dy), o, lse, ha, **kw))
 
     if has_mask:
         @jax.custom_vjp
         def f(q, k, v, head_mask):
-            return _fwd_call(q, k, v, _ha(head_mask, q.shape[2]), **kw)[0]
+            return _out(q, k, v, head_mask)
 
         def fwd(q, k, v, head_mask):
             return f(q, k, v, head_mask), (q, k, v, head_mask)
@@ -513,7 +531,7 @@ def _make_flash(causal, window, cap, scale, bq, bk, interpret, has_mask):
     else:
         @jax.custom_vjp
         def f(q, k, v):
-            return _fwd_call(q, k, v, _ha(None, q.shape[2]), **kw)[0]
+            return _out(q, k, v, None)
 
         def fwd(q, k, v):
             return f(q, k, v), (q, k, v)

@@ -28,8 +28,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.elastic_matmul import (_CompilerParams, _int_zero,
-                                          _last_block, _round_up)
+from repro.kernels.backend import default_interpret
+from repro.kernels.elastic_matmul import _int_zero, _last_block, _round_up
 
 
 def _kernel(s_ref, xs_ref, ws_ref, o_ref, acc_ref, *, nk):
@@ -103,7 +103,7 @@ def _grouped_call(xs, ws, ga, *, bm, bn, bk, interpret):
         functools.partial(_kernel, nk=nk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((G, Mp, Np), xs.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -136,7 +136,7 @@ def _make_grouped(bm, bn, bk, interpret):
 
 
 def grouped_elastic_matmul(xs, ws, g_active=None, *, bm=128, bn=128,
-                           bk=128, interpret=True):
+                           bk=128, interpret=None):
     """Differentiable grouped matmul with an expert-prefix skip.
 
     xs: (G, M, K); ws: (G, K, N); g_active: runtime int32 (None = all
@@ -144,5 +144,6 @@ def grouped_elastic_matmul(xs, ws, g_active=None, *, bm=128, bn=128,
     """
     ga = jnp.asarray(xs.shape[0] if g_active is None else g_active,
                      jnp.int32)
-    return _make_grouped(int(bm), int(bn), int(bk), bool(interpret))(
+    return _make_grouped(int(bm), int(bn), int(bk),
+                         default_interpret(interpret))(
         xs, ws, ga)

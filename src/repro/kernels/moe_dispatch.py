@@ -26,6 +26,10 @@ zero, so a narrow cohort moves (and back-propagates) only its own rows.
 
 All *narrow* int32 bookkeeping (argsort, searchsorted, slot tables) stays
 XLA in ``models.moe`` — only the wide (·,d) row traffic runs here.
+
+Rows move as (R, 1, d) arrays with (1, d) blocks: a block whose last two
+dims equal the array's is one the TPU lowering accepts, where a (1, d)
+block of an (R, d) array is not.
 """
 from __future__ import annotations
 
@@ -38,10 +42,6 @@ import jax.experimental.pallas.tpu as pltpu
 
 from repro.kernels.backend import default_interpret
 from repro.kernels.elastic_matmul import _int_zero
-
-# jax renamed TPUCompilerParams -> CompilerParams across releases
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
 
 
 def _gather_kernel(s_ref, x_ref, o_ref, *, n_rows):
@@ -63,7 +63,7 @@ def gather_index_map(n_src, n_rows):
     roofline gate's DMA accounting."""
     def m(r, s):
         return (jnp.where(s[n_rows + r] > 0,
-                          jnp.minimum(s[r], n_src - 1), 0), 0)
+                          jnp.minimum(s[r], n_src - 1), 0), 0, 0)
     return m
 
 
@@ -79,24 +79,26 @@ def gather_rows(x, idx, valid, *, interpret=None):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(R,),
-        in_specs=[pl.BlockSpec((1, d), gather_index_map(n_src, R))],
-        out_specs=pl.BlockSpec((1, d), lambda r, s: (r, 0)),
+        in_specs=[pl.BlockSpec((None, 1, d), gather_index_map(n_src, R))],
+        out_specs=pl.BlockSpec((None, 1, d), lambda r, s: (r, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_gather_kernel, n_rows=R),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, d), x.dtype),
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        out_shape=jax.ShapeDtypeStruct((R, 1, d), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(s, x)
+    )(s, x.reshape(n_src, 1, d))
+    return out.reshape(R, d)
 
 
 def _gather_reduce_kernel(s_ref, g_ref, *refs, k):
     y_refs, o_ref = refs[:-1], refs[-1]
+    g = g_ref[...].astype(jnp.float32)                  # (1, k)
     acc = jnp.zeros(o_ref.shape, jnp.float32)
     for j in range(k):
-        acc = acc + g_ref[0, j].astype(jnp.float32) * \
-            y_refs[j][...].astype(jnp.float32)
+        acc = acc + g[:, j:j + 1] * y_refs[j][...].astype(jnp.float32)
     o_ref[...] = acc.astype(o_ref.dtype)
 
 
@@ -105,7 +107,7 @@ def gather_reduce_index_maps(n_src, k):
     operand), each clamping its dest slot into range."""
     def mk(j):
         def m(t, s):
-            return (jnp.minimum(s[t * k + j], n_src - 1), 0)
+            return (jnp.minimum(s[t * k + j], n_src - 1), 0, 0)
         return m
     return [mk(j) for j in range(k)]
 
@@ -123,17 +125,19 @@ def gather_reduce(y, dest, gates, *, interpret=None):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(T,),
-        in_specs=[pl.BlockSpec((1, k), lambda t, s: (t, 0))] +
-                 [pl.BlockSpec((1, d), m) for m in maps],
-        out_specs=pl.BlockSpec((1, d), lambda t, s: (t, 0)),
+        in_specs=[pl.BlockSpec((None, 1, k), lambda t, s: (t, 0, 0))] +
+                 [pl.BlockSpec((None, 1, d), m) for m in maps],
+        out_specs=pl.BlockSpec((None, 1, d), lambda t, s: (t, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_gather_reduce_kernel, k=k),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, d), y.dtype),
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        out_shape=jax.ShapeDtypeStruct((T, 1, d), y.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(s, gates, *([y] * k))
+    )(s, gates.reshape(T, 1, k), *([y.reshape(n_src, 1, d)] * k))
+    return out.reshape(T, d)
 
 
 # ---------------------------------------------------------------------------

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels.backend import default_interpret
 from repro.kernels.dispatch import kernel_dispatch
 from repro.kernels.elastic_matmul import elastic_dense, elastic_matmul
 from repro.kernels.flash_attention import flash_attention
@@ -17,7 +18,7 @@ from repro.kernels.ssd_scan import ssd_scan
 
 
 def attention_op(q, k, v, *, causal=True, window=None, cap=None,
-                 head_mask=None, interpret=True, bq=128, bk=256):
+                 head_mask=None, interpret=None, bq=128, bk=256):
     """(B,Sq,H,D)x(B,Sk,KV,D) -> (B,Sq,H,D); contract matches
     models.attention.chunked_attention. Differentiable and elastic over
     ``head_mask`` (runtime head prefix) — thin alias over the dispatch
@@ -26,7 +27,7 @@ def attention_op(q, k, v, *, causal=True, window=None, cap=None,
                            cap=cap, bq=bq, bk=bk, interpret=interpret)
 
 
-def ssd_op(xh, dt, A, Bm, Cm, chunk, *, head_mask=None, interpret=True):
+def ssd_op(xh, dt, A, Bm, Cm, chunk, *, head_mask=None, interpret=None):
     """Contract matches models.ssm.ssd_chunked (returns (y, None) — the
     final state is only used by decode, which has its own path). Forward-
     only alias; the differentiable head-prefix op lives in dispatch."""
@@ -37,15 +38,16 @@ def ssd_op(xh, dt, A, Bm, Cm, chunk, *, head_mask=None, interpret=True):
     return y, None
 
 
-def elastic_mlp_matmul(x, w, k_active, *, interpret=True):
+def elastic_mlp_matmul(x, w, k_active, *, interpret=None):
     """(…, K) @ (K, N) with active output prefix k_active (CFL width).
     Back-compat alias over the differentiable ``elastic_dense``."""
     return elastic_dense(x, w, n_active=k_active, interpret=interpret)
 
 
-def model_kernels(interpret: bool = True):
+def model_kernels(interpret=None):
     """Back-compat model-facing dict: the dispatch table (mlp / moe / ssd /
     attention elastic ops — attention included since the flash kernel grew
-    its head prefix + backward)."""
-    return kernel_dispatch("interpret" if interpret else "tpu").table(
+    its head prefix + backward). ``interpret=None`` follows the host."""
+    backend = "interpret" if default_interpret(interpret) else "tpu"
+    return kernel_dispatch(backend).table(
         "transformer")

@@ -25,9 +25,15 @@ the last active head so no DMA is spent on the inactive suffix. Masked
 compute is therefore *skipped*, not zeroed, in both passes, and spec
 churn never recompiles (the scalar is traced).
 
-Block shapes: x (Q,P), B/C (Q,N), dt (Q,) with Q=chunk (≤256), P=head_dim
-(64..128), N=d_state (64..128) — everything fits VMEM with room for
-double buffering.
+Layout: the kernels run head-major — x (B,H,S,P), B/C (B,H,S,N), dt as
+(B,H,1,S) rows, A as (H,1,1) — so each block's last two dims are a
+(chunk, P|N) or (1, chunk) tile the TPU lowering accepts (one head out of
+a (.., S, H, P) array is not). The public (B,S,H,·) layout is transposed
+in the wrappers. Block shapes: x (Q,P), B/C (Q,N), dt (1,Q) with
+Q=chunk (≤256), P=head_dim (64..128), N=d_state (64..128) — everything
+fits VMEM with room for double buffering. The TPU lowering has no
+cumsum, so the in-chunk prefix sums are triangular masked reductions
+(O(Q²) VPU work, next to the Q×Q score matmuls already there).
 """
 from __future__ import annotations
 
@@ -40,9 +46,25 @@ import jax.experimental.pallas.tpu as pltpu
 
 from repro.kernels.backend import default_interpret
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+def _hm(x):
+    """(B, S, H, ·) <-> (B, H, S, ·)."""
+    return jnp.swapaxes(x, 1, 2)
+
+
+def _tri(q):
+    """(Q, Q) lower-triangular (i >= j) mask."""
+    return jax.lax.broadcasted_iota(jnp.int32, (q, q), 0) >= \
+        jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+
+
+def _cumsum(v, tri):
+    """Inclusive prefix sum of a (Q, 1) column, as a (Q, 1) column."""
+    return jnp.sum(jnp.where(tri, v.T, 0.0), axis=1, keepdims=True)
+
+
+def _total(v):
+    """Sum of a 2-D tile as a (1, 1) array."""
+    return jnp.sum(jnp.sum(v, axis=1, keepdims=True), axis=0, keepdims=True)
 
 
 def _kernel(s_ref, x_ref, dt_ref, a_ref, b_ref, c_ref, *refs, q, n_heads,
@@ -67,36 +89,34 @@ def _kernel(s_ref, x_ref, dt_ref, a_ref, b_ref, c_ref, *refs, q, n_heads,
         def _init():
             h_ref[...] = jnp.zeros_like(h_ref)
 
-        x = x_ref[0, :, 0, :].astype(jnp.float32)       # (Q,P)
-        dt = dt_ref[0, :, 0].astype(jnp.float32)        # (Q,)
-        A = a_ref[0]                                    # scalar
-        Bm = b_ref[0, :, 0, :].astype(jnp.float32)      # (Q,N)
-        Cm = c_ref[0, :, 0, :].astype(jnp.float32)      # (Q,N)
+        x = x_ref[...].astype(jnp.float32)               # (Q,P)
+        dt = dt_ref[...].astype(jnp.float32).T           # (Q,1)
+        A = a_ref[...]                                   # (1,1)
+        Bm = b_ref[...].astype(jnp.float32)              # (Q,N)
+        Cm = c_ref[...].astype(jnp.float32)              # (Q,N)
 
-        dA = dt * A                                     # (Q,) negative
-        cum = jnp.cumsum(dA)
-        diff = cum[:, None] - cum[None, :]
-        tri = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0) >= \
-            jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-        M = jnp.where(tri, jnp.exp(diff), 0.0)
+        tri = _tri(q)
+        cum = _cumsum(dt * A, tri)                       # (Q,1) negative
+        cum_end = cum[q - 1:, :]                         # (1,1)
+        M = jnp.where(tri, jnp.exp(cum - cum.T), 0.0)
         CB = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        xdt = x * dt[:, None]
+        xdt = x * dt
         y_intra = jax.lax.dot_general(CB * M, xdt, (((1,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)
         h = h_ref[...]                                   # (P,N)
         if with_states:
-            st_ref[0, 0, 0] = h.astype(st_ref.dtype)     # chunk-initial state
-        y_inter = jnp.exp(cum)[:, None] * jax.lax.dot_general(
+            st_ref[...] = h.astype(st_ref.dtype)         # chunk-initial state
+        y_inter = jnp.exp(cum) * jax.lax.dot_general(
             Cm, h, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        y_ref[0, :, 0, :] = (y_intra + y_inter).astype(y_ref.dtype)
+        y_ref[...] = (y_intra + y_inter).astype(y_ref.dtype)
 
-        decay_end = jnp.exp(cum[-1] - cum)               # (Q,)
-        S_c = jax.lax.dot_general(xdt * decay_end[:, None], Bm,
+        decay_end = jnp.exp(cum_end - cum)               # (Q,1)
+        S_c = jax.lax.dot_general(xdt * decay_end, Bm,
                                   (((0,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-        h_ref[...] = h * jnp.exp(cum[-1]) + S_c
+        h_ref[...] = h * jnp.exp(cum_end) + S_c
 
 
 def _head_clamp(H):
@@ -121,10 +141,10 @@ def ssd_fwd_index_maps(H):
     (x, dt, A, B, C) — exported so the roofline gate can measure DMA
     block requests from the *actual* maps the kernel runs with."""
     hcl, cc = _head_clamp(H), _chunk_clamp(H)
-    xm = lambda bh, ci, s: (bh // H, cc(bh, ci, s), hcl(bh, s), 0)
+    xm = lambda bh, ci, s: (bh // H, hcl(bh, s), cc(bh, ci, s), 0)
     return [xm,
-            lambda bh, ci, s: (bh // H, cc(bh, ci, s), hcl(bh, s)),
-            lambda bh, ci, s: (hcl(bh, s),),
+            lambda bh, ci, s: (bh // H, hcl(bh, s), 0, cc(bh, ci, s)),
+            lambda bh, ci, s: (hcl(bh, s), 0, 0),
             xm, xm]
 
 
@@ -134,13 +154,34 @@ def ssd_bwd_index_maps(H, nc):
     walks chunks in reverse while the grid stays forward-ordered."""
     hcl, cc = _head_clamp(H), _chunk_clamp(H)
     rc = lambda bh, ci, s: cc(bh, nc - 1 - ci, s)
-    xm = lambda bh, ci, s: (bh // H, rc(bh, ci, s), hcl(bh, s), 0)
+    xm = lambda bh, ci, s: (bh // H, hcl(bh, s), rc(bh, ci, s), 0)
     return [xm,
-            lambda bh, ci, s: (bh // H, rc(bh, ci, s), hcl(bh, s)),
-            lambda bh, ci, s: (hcl(bh, s),),
+            lambda bh, ci, s: (bh // H, hcl(bh, s), 0, rc(bh, ci, s)),
+            lambda bh, ci, s: (hcl(bh, s), 0, 0),
             xm, xm,
             lambda bh, ci, s: (bh // H, rc(bh, ci, s), hcl(bh, s), 0, 0),
             xm]
+
+
+def _head_major(xh, dt, A, Bm, Cm):
+    """Kernel operands: x/B/C (B,H,S,·), dt (B,H,1,S), A (H,1,1); B/C
+    repeated from G groups onto the H heads."""
+    H = xh.shape[2]
+    rep = H // Bm.shape[2]
+    if rep != 1:
+        Bm = jnp.repeat(Bm, rep, axis=2)
+        Cm = jnp.repeat(Cm, rep, axis=2)
+    return (_hm(xh), _hm(dt)[:, :, None, :], A.reshape(H, 1, 1), _hm(Bm),
+            _hm(Cm))
+
+
+def _seq_specs(maps, chunk, P, N):
+    """BlockSpecs of (x, dt, A, B, C) under ``maps``."""
+    return [pl.BlockSpec((None, None, chunk, P), maps[0]),
+            pl.BlockSpec((None, None, 1, chunk), maps[1]),
+            pl.BlockSpec((None, 1, 1), maps[2]),
+            pl.BlockSpec((None, None, chunk, N), maps[3]),
+            pl.BlockSpec((None, None, chunk, N), maps[4])]
 
 
 @functools.partial(jax.jit,
@@ -157,52 +198,42 @@ def ssd_scan(xh, dt, A, Bm, Cm, chunk: int = 128, *, h_active=None,
     """
     interpret = default_interpret(interpret)
     B, S, H, P = xh.shape
-    G, N = Bm.shape[2], Bm.shape[3]
+    N = Bm.shape[3]
     assert S % chunk == 0
     nc = S // chunk
-    rep = H // G
-    if rep != 1:
-        Bm = jnp.repeat(Bm, rep, axis=2)
-        Cm = jnp.repeat(Cm, rep, axis=2)
-    grid = (B * H, nc)
     ha = jnp.asarray(H if h_active is None else h_active,
                      jnp.int32).reshape(1)
 
-    maps = ssd_fwd_index_maps(H)
-    in_specs = [
-        pl.BlockSpec((1, chunk, 1, P), maps[0]),
-        pl.BlockSpec((1, chunk, 1), maps[1]),
-        pl.BlockSpec((1,), maps[2]),
-        pl.BlockSpec((1, chunk, 1, N), maps[3]),
-        pl.BlockSpec((1, chunk, 1, N), maps[4]),
-    ]
-    y_spec = pl.BlockSpec((1, chunk, 1, P),
-                          lambda bh, ci, s: (bh // H, ci, bh % H, 0))
+    y_spec = pl.BlockSpec((None, None, chunk, P),
+                          lambda bh, ci, s: (bh // H, bh % H, ci, 0))
     out_specs = y_spec
-    out_shape = jax.ShapeDtypeStruct(xh.shape, xh.dtype)
+    out_shape = jax.ShapeDtypeStruct((B, H, S, P), xh.dtype)
     if return_states:
         st_spec = pl.BlockSpec(
-            (1, 1, 1, P, N),
+            (None, None, None, P, N),
             lambda bh, ci, s: (bh // H, ci, bh % H, 0, 0))
         out_specs = [y_spec, st_spec]
         out_shape = [out_shape,
                      jax.ShapeDtypeStruct((B, nc, H, P, N), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=in_specs,
+        grid=(B * H, nc),
+        in_specs=_seq_specs(ssd_fwd_index_maps(H), chunk, P, N),
         out_specs=out_specs,
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_kernel, q=chunk, n_heads=H,
                           with_states=return_states),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(ha, xh, dt, A, Bm, Cm)
+    )(ha, *_head_major(xh, dt, A, Bm, Cm))
+    if return_states:
+        return _hm(out[0]), out[1]
+    return _hm(out)
 
 
 def _bwd_kernel(s_ref, x_ref, dt_ref, a_ref, b_ref, c_ref, st_ref, dy_ref,
@@ -215,7 +246,8 @@ def _bwd_kernel(s_ref, x_ref, dt_ref, a_ref, b_ref, c_ref, st_ref, dy_ref,
     chunk's dx/ddt/du/dB/dC blocks, and leaves ``dh = E_Q·dh + dh_y`` for
     the chunk before it. ``du`` is the cotangent of ``u = dt·A`` — the
     host reduces it to dA (and folds it into ddt) so the kernel never
-    needs a cross-chunk reduction.
+    needs a cross-chunk reduction. Per-position vectors are (Q,1)
+    columns; ddt/du leave as (1,Q) rows.
     """
     bh, ci = pl.program_id(0), pl.program_id(1)
     head = jax.lax.rem(bh, n_heads)
@@ -235,25 +267,24 @@ def _bwd_kernel(s_ref, x_ref, dt_ref, a_ref, b_ref, c_ref, st_ref, dy_ref,
         def _init():
             dh_ref[...] = jnp.zeros_like(dh_ref)
 
-        x = x_ref[0, :, 0, :].astype(jnp.float32)       # (Q,P)
-        dt = dt_ref[0, :, 0].astype(jnp.float32)        # (Q,)
-        A = a_ref[0]
-        Bm = b_ref[0, :, 0, :].astype(jnp.float32)      # (Q,N)
-        Cm = c_ref[0, :, 0, :].astype(jnp.float32)      # (Q,N)
-        h_in = st_ref[0, 0, 0].astype(jnp.float32)      # (P,N)
-        dy = dy_ref[0, :, 0, :].astype(jnp.float32)     # (Q,P)
+        x = x_ref[...].astype(jnp.float32)               # (Q,P)
+        dt = dt_ref[...].astype(jnp.float32).T           # (Q,1)
+        A = a_ref[...]                                   # (1,1)
+        Bm = b_ref[...].astype(jnp.float32)              # (Q,N)
+        Cm = c_ref[...].astype(jnp.float32)              # (Q,N)
+        h_in = st_ref[...].astype(jnp.float32)           # (P,N)
+        dy = dy_ref[...].astype(jnp.float32)             # (Q,P)
 
-        cum = jnp.cumsum(dt * A)
-        diff = cum[:, None] - cum[None, :]
-        tri = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0) >= \
-            jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-        L = jnp.where(tri, jnp.exp(diff), 0.0)
+        tri = _tri(q)
+        cum = _cumsum(dt * A, tri)                       # (Q,1)
+        cum_end = cum[q - 1:, :]                         # (1,1)
+        L = jnp.where(tri, jnp.exp(cum - cum.T), 0.0)
         CB = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        xdt = x * dt[:, None]
-        e = jnp.exp(cum)                                 # (Q,)
-        E_Q = jnp.exp(cum[-1])
-        w_end = jnp.exp(cum[-1] - cum)                   # (Q,)
+        xdt = x * dt
+        e = jnp.exp(cum)                                 # (Q,1)
+        E_Q = jnp.exp(cum_end)                           # (1,1)
+        w_end = jnp.exp(cum_end - cum)                   # (Q,1)
 
         dh_out = dh_ref[...]                             # (P,N)
 
@@ -272,37 +303,39 @@ def _bwd_kernel(s_ref, x_ref, dt_ref, a_ref, b_ref, c_ref, st_ref, dy_ref,
         # inter-chunk read: y_inter = e ∘ (C @ h_inᵀ)
         CH = jax.lax.dot_general(Cm, h_in, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        dcum = DL.sum(1) - DL.sum(0) + jnp.sum(dy * CH, axis=1) * e
-        dC = dC + e[:, None] * jax.lax.dot_general(
+        dcum = (jnp.sum(DL, axis=1, keepdims=True) -
+                jnp.sum(DL, axis=0, keepdims=True).T +
+                jnp.sum(dy * CH, axis=1, keepdims=True) * e)
+        dC = dC + e * jax.lax.dot_general(
             dy, h_in, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dh_y = jax.lax.dot_general(dy * e[:, None], Cm,
+        dh_y = jax.lax.dot_general(dy * e, Cm,
                                    (((0,), (0,)), ((), ())),
                                    preferred_element_type=jnp.float32)
 
         # state write: h_out = E_Q·h_in + Σ_s w_s·(xdt_s ⊗ B_s)
         XD = jax.lax.dot_general(xdt, dh_out, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        T = jnp.sum(XD * Bm, axis=1)                     # (Q,)
-        dxdt = dxdt + w_end[:, None] * jax.lax.dot_general(
+        T = jnp.sum(XD * Bm, axis=1, keepdims=True)      # (Q,1)
+        dxdt = dxdt + w_end * jax.lax.dot_general(
             Bm, dh_out, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dB = dB + w_end[:, None] * XD
+        dB = dB + w_end * XD
         dcum = dcum - T * w_end
-        last = E_Q * jnp.sum(dh_out * h_in) + jnp.sum(T * w_end)
+        last = E_Q * _total(dh_out * h_in) + _total(T * w_end)   # (1,1)
 
         # cum = cumsum(u): du_s = Σ_{t≥s} dcum_t; `last` is the cum[-1]
         # term (decay-to-end + carried state), which lands on every s.
-        du = (jnp.sum(dcum) + last) - jnp.cumsum(dcum) + dcum
+        du = (_total(dcum) + last) - _cumsum(dcum, tri) + dcum
 
         dh_ref[...] = dh_out * E_Q + dh_y
 
-        dx_ref[0, :, 0, :] = (dxdt * dt[:, None]).astype(dx_ref.dtype)
-        ddt_ref[0, :, 0] = (jnp.sum(dxdt * x, axis=1) +
-                            du * A).astype(ddt_ref.dtype)
-        du_ref[0, :, 0] = du.astype(du_ref.dtype)
-        db_ref[0, :, 0, :] = dB.astype(db_ref.dtype)
-        dc_ref[0, :, 0, :] = dC.astype(dc_ref.dtype)
+        dx_ref[...] = (dxdt * dt).astype(dx_ref.dtype)
+        ddt_ref[...] = (jnp.sum(dxdt * x, axis=1, keepdims=True) +
+                        du * A).T.astype(ddt_ref.dtype)
+        du_ref[...] = du.T.astype(du_ref.dtype)
+        db_ref[...] = dB.astype(db_ref.dtype)
+        dc_ref[...] = dC.astype(dc_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -322,36 +355,25 @@ def ssd_scan_bwd(xh, dt, A, Bm, Cm, states, dy, chunk: int = 128, *,
     assert S % chunk == 0
     nc = S // chunk
     rep = H // G
-    Bf, Cf = Bm, Cm
-    if rep != 1:
-        Bf = jnp.repeat(Bm, rep, axis=2)
-        Cf = jnp.repeat(Cm, rep, axis=2)
-    grid = (B * H, nc)
     ha = jnp.asarray(H if h_active is None else h_active,
                      jnp.int32).reshape(1)
 
     maps = ssd_bwd_index_maps(H, nc)
-    flip = lambda bh, ci, s: (bh // H, nc - 1 - ci, bh % H, 0)
+    flip = lambda bh, ci, s: (bh // H, bh % H, nc - 1 - ci, 0)
+    row_flip = lambda bh, ci, s: (bh // H, bh % H, 0, nc - 1 - ci)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, chunk, 1, P), maps[0]),
-            pl.BlockSpec((1, chunk, 1), maps[1]),
-            pl.BlockSpec((1,), maps[2]),
-            pl.BlockSpec((1, chunk, 1, N), maps[3]),
-            pl.BlockSpec((1, chunk, 1, N), maps[4]),
-            pl.BlockSpec((1, 1, 1, P, N), maps[5]),
-            pl.BlockSpec((1, chunk, 1, P), maps[6]),
+        grid=(B * H, nc),
+        in_specs=_seq_specs(maps, chunk, P, N) + [
+            pl.BlockSpec((None, None, None, P, N), maps[5]),
+            pl.BlockSpec((None, None, chunk, P), maps[6]),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, P), flip),
-            pl.BlockSpec((1, chunk, 1),
-                         lambda bh, ci, s: (bh // H, nc - 1 - ci, bh % H)),
-            pl.BlockSpec((1, chunk, 1),
-                         lambda bh, ci, s: (bh // H, nc - 1 - ci, bh % H)),
-            pl.BlockSpec((1, chunk, 1, N), flip),
-            pl.BlockSpec((1, chunk, 1, N), flip),
+            pl.BlockSpec((None, None, chunk, P), flip),
+            pl.BlockSpec((None, None, 1, chunk), row_flip),
+            pl.BlockSpec((None, None, 1, chunk), row_flip),
+            pl.BlockSpec((None, None, chunk, N), flip),
+            pl.BlockSpec((None, None, chunk, N), flip),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
     )
@@ -359,16 +381,18 @@ def ssd_scan_bwd(xh, dt, A, Bm, Cm, states, dy, chunk: int = 128, *,
         functools.partial(_bwd_kernel, q=chunk, n_heads=H),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct(xh.shape, xh.dtype),
-            jax.ShapeDtypeStruct(dt.shape, dt.dtype),
-            jax.ShapeDtypeStruct(dt.shape, jnp.float32),
-            jax.ShapeDtypeStruct(Bf.shape, Bm.dtype),
-            jax.ShapeDtypeStruct(Cf.shape, Cm.dtype),
+            jax.ShapeDtypeStruct((B, H, S, P), xh.dtype),
+            jax.ShapeDtypeStruct((B, H, 1, S), dt.dtype),
+            jax.ShapeDtypeStruct((B, H, 1, S), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, S, N), Bm.dtype),
+            jax.ShapeDtypeStruct((B, H, S, N), Cm.dtype),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(ha, xh, dt, A, Bf, Cf, states, dy)
+    )(ha, *_head_major(xh, dt, A, Bm, Cm), states, _hm(dy))
+    ddt, du = _hm(ddt[:, :, 0, :]), _hm(du[:, :, 0, :])
+    dBf, dCf = _hm(dBf), _hm(dCf)
     # u = dt·A: the A cotangent is a host-side reduction of du (zero for
     # skipped heads, so dA inherits the prefix for free).
     dA = jnp.einsum("bsh,bsh->h", du,
@@ -376,4 +400,4 @@ def ssd_scan_bwd(xh, dt, A, Bm, Cm, states, dy, chunk: int = 128, *,
     if rep != 1:
         dBf = dBf.reshape(B, S, G, rep, N).sum(axis=3)
         dCf = dCf.reshape(B, S, G, rep, N).sum(axis=3)
-    return dxh, ddt, dA, dBf, dCf
+    return _hm(dxh), ddt, dA, dBf, dCf

@@ -2,40 +2,38 @@
 this module never touches jax device state."""
 from __future__ import annotations
 
-import contextlib
+from typing import Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices: Optional[Sequence] = None):
+    """The one mesh constructor: every axis ``Auto``, so GSPMD propagates
+    shardings through the vmapped client step's reshapes (``jax.make_mesh``
+    defaults to ``Explicit`` axes, which reject them)."""
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def activate_mesh(mesh):
-    """Version-compat ``jax.set_mesh``: make ``mesh`` the ambient mesh so
-    sharding-aware module paths (``get_abstract_mesh`` readers in
-    models/layers, models/moe, models/transformer) see its axis names
-    during trace. jax >= 0.5 exposes ``jax.set_mesh``; on 0.4.x only the
-    internal abstract-mesh context manager exists — fall back to it, and
-    to a null context when neither is available (the readers already
-    degrade to unsharded paths)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    try:
-        from jax._src import mesh as _mesh_lib
-        return _mesh_lib.set_abstract_mesh(mesh.abstract_mesh)
-    except Exception:       # pragma: no cover — degrade, don't crash
-        return contextlib.nullcontext()
+    """Make ``mesh`` the ambient mesh (``jax.set_mesh``) so sharding-aware
+    module paths (``get_abstract_mesh`` readers in models/layers,
+    models/moe, models/transformer) see its axis names during trace."""
+    return jax.set_mesh(mesh)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Small mesh over whatever devices exist (tests / CPU)."""
     n = len(jax.devices())
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 # Hardware constants for the roofline (TPU v5e)

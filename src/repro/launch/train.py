@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.checkpoint import save_checkpoint
 from repro.configs import ARCHS, get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_train_step
 from repro.models import transformer as T
 
@@ -100,6 +101,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--checkpoint", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     train(args.arch, steps=args.steps, batch=args.batch, seq=args.seq,
           lr=args.lr, use_reduced=not args.full, n_layers=args.layers,
           d_model=args.d_model, seed=args.seed,

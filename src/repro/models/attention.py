@@ -58,26 +58,6 @@ def mla_init(key, d_model, n_heads, mla):
 # ---------------------------------------------------------------------------
 # blockwise attention (reference/XLA path)
 # ---------------------------------------------------------------------------
-def _sharding_hint(x, *spec):
-    """Best-effort with_sharding_constraint (no-op without a mesh)."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        names = set(getattr(mesh, "axis_names", ()) or ())
-        if not names:
-            return x
-
-        def fix(s):
-            if isinstance(s, tuple):
-                t = tuple(a for a in s if a in names)
-                return t if t else None
-            return s if (s is None or s in names) else None
-        import jax.sharding as shd
-        return jax.lax.with_sharding_constraint(
-            x, shd.PartitionSpec(*[fix(s) for s in spec]))
-    except Exception:       # pragma: no cover
-        return x
-
-
 def _band_count(nq: int, target: int = 8) -> int:
     """Largest divisor of nq not exceeding target."""
     best = 1
@@ -214,11 +194,8 @@ def dispatch_attention(q, k, v, **kw):
     B, Sq, H, D = q.shape
     KV = k.shape[2]
     G = H // KV
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        names = set(getattr(mesh, "axis_names", ()) or ())
-    except Exception:            # pragma: no cover
-        names = set()
+    mesh = jax.sharding.get_abstract_mesh()
+    names = set(mesh.axis_names)
     m = mesh.shape["model"] if "model" in names else 1
     if m <= 1 or H % m != 0 or Sq == 1:
         return chunked_attention(q, k, v, **kw)

@@ -198,11 +198,8 @@ def _embed_lookup(table, ids):
     backward is a purely local scatter-add into the local shard.
     """
     from jax.sharding import PartitionSpec as P
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        names = set(getattr(mesh, "axis_names", ()) or ())
-    except Exception:            # pragma: no cover
-        names = set()
+    mesh = jax.sharding.get_abstract_mesh()
+    names = set(mesh.axis_names)
     V = table.shape[0]
     msize = mesh.shape["model"] if "model" in names else 1
     if "model" not in names or V % msize != 0 or ids.ndim != 2 \

@@ -186,11 +186,8 @@ def moe_forward(p, x, moe_cfg, *, act="silu",
         jnp.square(jax.nn.logsumexp(logits, axis=-1)))
 
     # --- expert compute (sharded when possible) ---------------------------
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        names = set(getattr(mesh, "axis_names", ()) or ())
-    except Exception:            # pragma: no cover
-        names = set()
+    mesh = jax.sharding.get_abstract_mesh()
+    names = set(mesh.axis_names)
     msize = mesh.shape["model"] if "model" in names else 1
     wg = p.get("wg")
 

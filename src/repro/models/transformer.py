@@ -364,23 +364,20 @@ def _unembed_w(params, cfg):
 
 
 def _constrain(x, *spec):
-    """Best-effort sharding constraint: only names present in the ambient
-    abstract mesh are kept (no-op on unmeshed single-device runs)."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        names = set(getattr(mesh, "axis_names", ()) or ())
-        if not names:
-            return x
-
-        def fix(s):
-            if isinstance(s, tuple):
-                t = tuple(a for a in s if a in names)
-                return t if t else None
-            return s if (s is None or s in names) else None
-        return jax.lax.with_sharding_constraint(
-            x, jax.sharding.PartitionSpec(*[fix(s) for s in spec]))
-    except Exception:       # pragma: no cover — constraint is advisory
+    """Sharding constraint over the ambient abstract mesh: only names
+    present in the mesh are kept (no-op on unmeshed single-device runs).
+    Under a mesh a constraint that cannot be applied raises."""
+    names = set(jax.sharding.get_abstract_mesh().axis_names)
+    if not names:
         return x
+
+    def fix(s):
+        if isinstance(s, tuple):
+            t = tuple(a for a in s if a in names)
+            return t if t else None
+        return s if (s is None or s in names) else None
+    return jax.lax.with_sharding_constraint(
+        x, jax.sharding.PartitionSpec(*[fix(s) for s in spec]))
 
 
 @jax.custom_vjp

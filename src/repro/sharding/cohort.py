@@ -21,6 +21,8 @@ from typing import Optional, Sequence
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
+
 
 def cohort_mesh(n_shards: Optional[int] = None, *,
                 devices: Optional[Sequence] = None) -> jax.sharding.Mesh:
@@ -29,7 +31,7 @@ def cohort_mesh(n_shards: Optional[int] = None, *,
     n = n_shards or len(devs)
     if n > len(devs):
         raise ValueError(f"cohort_mesh: {n} shards > {len(devs)} devices")
-    return jax.make_mesh((n,), ("cohort",), devices=devs[:n])
+    return make_mesh((n,), ("cohort",), devices=devs[:n])
 
 
 def cohort_axis_sharding(mesh: jax.sharding.Mesh) -> NamedSharding:

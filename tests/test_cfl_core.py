@@ -7,10 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # container without hypothesis: seeded sweeps
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs.paper_cnn import CNNConfig
 from repro.core import (AccuracyPredictor, LatencyTable, SubmodelSpec,

@@ -6,10 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # container without hypothesis: seeded sweeps
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.data import (apply_quality, gaussian_blur, iid_partition,
                         make_dataset, mixed_quality_dataset, noniid_partition,

@@ -20,10 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # container without hypothesis: seeded sweeps
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.checkpoint.fleet import (restore_fleet_checkpoint,
                                     save_fleet_checkpoint, snapshot_server)
