@@ -1,0 +1,6 @@
+"""Device milliseconds per run of the prefill program, from the trace."""
+from chipbench.harness.readers import per_run_ms
+
+
+def compute(run):
+    return per_run_ms(run, "jit__prefill")
