@@ -58,41 +58,28 @@ TOL = 1e-5          # the documented dense-vs-kernel and prefill parity bound
 # above 1e-6) and 1.245e-5 on the CPU. 1e-4 is 8x that; a miswired mask
 # or tile moves a step by its own size, ~1e-2.
 STEP_TOL = 1e-4
-# JAX's event around each XLA compile (a persistent-cache load included)
-COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
-class CompileMeter:
-    """Counts XLA compilations and their seconds (persistent-cache loads
-    included), and the persistent-cache hits among them, from JAX's own
-    compile events."""
+def compile_summary(start, end) -> str:
+    """Compilations (persistent-cache loads included), their seconds and
+    the persistent-cache hits between two ``repro.obs`` counter
+    snapshots."""
+    def d(k):
+        return end.get(k, 0) - start.get(k, 0)
+    return (f"compiles={d('compile.count')} "
+            f"compile_s={d('compile.seconds'):.3f} "
+            f"cache_hits={d('compile.cache_hits')}")
 
-    def __init__(self):
-        self.n, self.secs, self.hits = 0, 0.0, 0
 
-        def on_duration(name, secs, **_):
-            if name == COMPILE_EVENT:
-                self.n += 1
-                self.secs += secs
-
-        def on_event(name, **_):
-            if name == CACHE_HIT_EVENT:
-                self.hits += 1
-        jax.monitoring.register_event_duration_secs_listener(on_duration)
-        jax.monitoring.register_event_listener(on_event)
-
-    def summary(self, n0=0, s0=0.0, h0=0) -> str:
-        return (f"compiles={self.n - n0} compile_s={self.secs - s0:.3f} "
-                f"cache_hits={self.hits - h0}")
-
-    @contextlib.contextmanager
-    def phase(self, name: str):
-        start, t0 = (self.n, self.secs, self.hits), time.perf_counter()
-        print(f"[{name}] start", flush=True)
+@contextlib.contextmanager
+def phase(name: str):
+    from repro import obs
+    start, t0 = obs.counters(), time.perf_counter()
+    print(f"[{name}] start", flush=True)
+    with obs.span("smoke." + name):
         yield
-        print(f"[{name}] done: wall_s={time.perf_counter() - t0:.3f} "
-              f"{self.summary(*start)}", flush=True)
+    print(f"[{name}] done: wall_s={time.perf_counter() - t0:.3f} "
+          f"{compile_summary(start, obs.counters())}", flush=True)
 
 
 def engine_programs(engine) -> int:
@@ -390,20 +377,21 @@ def main(argv=None) -> int:
 
     sys.path.insert(0, os.path.join(ROOT, "src"))
     from repro.launch.compile_cache import enable_compile_cache
+    from repro import obs
     print(f"compilation cache: {enable_compile_cache()}", flush=True)
-    meter = CompileMeter()
+    start = obs.counters()
 
     if args.chips == 4:
-        with meter.phase("cohort-mesh"):
+        with phase("cohort-mesh"):
             phase_cohort_mesh(4)
     else:
-        with meter.phase("train"):
+        with phase("train"):
             phase_train()
-        with meter.phase("kernels"):
+        with phase("kernels"):
             phase_kernels("tpu")
-        with meter.phase("serve"):
+        with phase("serve"):
             phase_serve()
-    print(f"total: {meter.summary()}", flush=True)
+    print(f"total: {compile_summary(start, obs.counters())}", flush=True)
     print(json.dumps({"ok": True, "device": device}))
     return 0
 

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.elastic import ElasticFamily, family_for
 from repro.optim import adamw, apply_updates
 
@@ -112,6 +113,7 @@ class AccuracyPredictor:
         return float(self._net(self.params, x)[0])
 
     def predict_batch(self, specs: Sequence, quality: int) -> np.ndarray:
+        obs.count("search.predict_calls")
         x = jnp.asarray(np.stack([featurize(self.family, s, quality)
                                   for s in specs]))
         return np.asarray(self._net(self.params, x))

@@ -19,6 +19,7 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.elastic import family_for
 from repro.core.latency import LatencyTable
 from repro.core.predictor import AccuracyPredictor
@@ -87,8 +88,11 @@ def search_all_workers(cfg, predictor: AccuracyPredictor,
                        seed: int = 0) -> List:
     """Alg. 1 outer loop: one submodel per worker."""
     fam = family_for(cfg)
-    return [search_submodel(fam, predictor, latency, device=d, quality=q,
-                            latency_bound=lb, search_cfg=search_cfg,
-                            seed=seed * 977 + k)
-            for k, (d, q, lb) in enumerate(zip(devices, qualities,
-                                               latency_bounds))]
+    out = []
+    for k, (d, q, lb) in enumerate(zip(devices, qualities, latency_bounds)):
+        with obs.span("cfl.search.worker"):
+            out.append(search_submodel(
+                fam, predictor, latency, device=d, quality=q,
+                latency_bound=lb, search_cfg=search_cfg,
+                seed=seed * 977 + k))
+    return out

@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.aggregate import (aggregate, aggregate_coverage,
                                   apply_server_update)
 # re-exported for API compatibility with the PR-1 CNN-specific engine
@@ -562,30 +563,39 @@ class BatchedRoundEngine:
                 batch_size=batch_size, epochs=epochs, seeds=seeds,
                 eval_datasets=eval_datasets, prefetch_hook=prefetch_hook)
         sh = self.cohort_sharding(len(specs))
-        masks = self._cohort_masks(specs)
-        x, y = self._cohort_data(datasets)
-        staged = self._take_staged(datasets, eval_datasets, None,
-                                   batch_size, epochs, seeds)
-        if staged is not None:
-            stream, n_steps = staged["stream"], staged["n_steps"]
-        else:
-            stream, n_steps = self._full_stream(datasets, batch_size,
-                                                epochs, seeds)
-        theta0_stacked = shard_cohort(theta0_stacked, sh)
-        if eval_datasets is None:
-            deltas, trained = self._train(
-                theta0_stacked, masks.param_mask, masks.fwd, x, y, *stream)
+        with obs.span("engine.pack"):
+            with obs.span("engine.masks"):
+                masks = self._cohort_masks(specs)
+            with obs.span("engine.data"):
+                x, y = self._cohort_data(datasets)
+                pack = None if eval_datasets is None \
+                    else self._eval_pack(eval_datasets)
+            with obs.span("engine.stream"):
+                staged = self._take_staged(datasets, eval_datasets, None,
+                                           batch_size, epochs, seeds)
+                if staged is not None:
+                    stream, n_steps = staged["stream"], staged["n_steps"]
+                else:
+                    stream, n_steps = self._full_stream(
+                        datasets, batch_size, epochs, seeds)
+            theta0_stacked = shard_cohort(theta0_stacked, sh)
+        if pack is None:
+            with obs.span("engine.dispatch"):
+                deltas, trained = self._train(
+                    theta0_stacked, masks.param_mask, masks.fwd, x, y,
+                    *stream)
             if prefetch_hook is not None:
                 prefetch_hook()
             return CohortResult(deltas, trained, masks, n_steps)
-        pack = self._eval_pack(eval_datasets)
-        deltas, trained, accs = self._train_eval(
-            theta0_stacked, masks.param_mask, masks.fwd, x, y,
-            *stream, pack.x, pack.y, pack.valid)
+        with obs.span("engine.dispatch"):
+            deltas, trained, accs = self._train_eval(
+                theta0_stacked, masks.param_mask, masks.fwd, x, y,
+                *stream, pack.x, pack.y, pack.valid)
         if prefetch_hook is not None:
             prefetch_hook()     # overlaps with the in-flight fused program
-        return CohortResult(deltas, trained, masks, n_steps,
-                            np.asarray(accs))
+        with obs.span("engine.wait"):
+            accs = np.asarray(accs)
+        return CohortResult(deltas, trained, masks, n_steps, accs)
 
     def _train_cohort_subset(self, theta0_stacked, specs: Sequence,
                              datasets: Sequence[Dict], participation, *,
@@ -603,27 +613,33 @@ class BatchedRoundEngine:
                 f"per-slot specs/seeds must match the padded cohort size "
                 f"{m}, got {len(specs)}/{len(seeds)}")
         sh = self.cohort_sharding(m)
-        masks = self._cohort_masks(specs)
-        t = self._take_staged(datasets, eval_datasets, part, batch_size,
-                              epochs, seeds)
-        if t is None:
-            t = self._subset_tensors(datasets, part, batch_size, epochs,
-                                     seeds, eval_datasets)
-        theta0_stacked = shard_cohort(theta0_stacked, sh)
+        with obs.span("engine.pack"):
+            with obs.span("engine.masks"):
+                masks = self._cohort_masks(specs)
+            with obs.span("engine.stream"):
+                t = self._take_staged(datasets, eval_datasets, part,
+                                      batch_size, epochs, seeds)
+                if t is None:
+                    t = self._subset_tensors(datasets, part, batch_size,
+                                             epochs, seeds, eval_datasets)
+            theta0_stacked = shard_cohort(theta0_stacked, sh)
         if eval_datasets is None:
-            deltas, trained = self._train(
-                theta0_stacked, masks.param_mask, masks.fwd, t["x"],
-                t["y"], *t["stream"])
+            with obs.span("engine.dispatch"):
+                deltas, trained = self._train(
+                    theta0_stacked, masks.param_mask, masks.fwd, t["x"],
+                    t["y"], *t["stream"])
             if prefetch_hook is not None:
                 prefetch_hook()
             return CohortResult(deltas, trained, masks, t["n_steps"])
-        deltas, trained, accs = self._train_eval(
-            theta0_stacked, masks.param_mask, masks.fwd, t["x"], t["y"],
-            *t["stream"], t["ex"], t["ey"], t["ev"])
+        with obs.span("engine.dispatch"):
+            deltas, trained, accs = self._train_eval(
+                theta0_stacked, masks.param_mask, masks.fwd, t["x"],
+                t["y"], *t["stream"], t["ex"], t["ey"], t["ev"])
         if prefetch_hook is not None:
             prefetch_hook()     # overlaps with the in-flight fused program
-        return CohortResult(deltas, trained, masks, t["n_steps"],
-                            np.asarray(accs))
+        with obs.span("engine.wait"):
+            accs = np.asarray(accs)
+        return CohortResult(deltas, trained, masks, t["n_steps"], accs)
 
     def _cohort_masks(self, specs: Sequence) -> CohortMasks:
         key = tuple(self.family.genes(s) for s in specs)
@@ -690,7 +706,8 @@ class BatchedRoundEngine:
         same fp32 partial sums, different reduction order)."""
         from repro.core.aggregate import (aggregate_apply,
                                           aggregate_apply_hierarchical)
-        theta0 = self.broadcast_params(params, len(specs))
+        with obs.span("engine.broadcast"):
+            theta0 = self.broadcast_params(params, len(specs))
         res = self.train_cohort(theta0, specs, datasets,
                                 batch_size=batch_size, epochs=epochs,
                                 seeds=seeds, eval_datasets=test_datasets,
@@ -698,21 +715,23 @@ class BatchedRoundEngine:
                                 prefetch_hook=prefetch_hook)
         covs = res.masks.param_mask if coverage_norm else None
         sh = self.cohort_sharding(len(specs))
-        if participation is None:
-            weights = jnp.asarray(sizes, jnp.float32)
-            part = None
-        else:
-            weights = jnp.asarray(
-                np.asarray(participation.weights, np.float32))
-            part = jnp.asarray(np.asarray(participation.valid, np.float32))
-        if sh is not None:
-            new_params = aggregate_apply_hierarchical(
-                params, res.deltas, covs, weights, mesh=sh.mesh,
-                coverage_norm=coverage_norm, participation=part)
-        else:
-            new_params = aggregate_apply(
-                params, res.deltas, covs, weights,
-                coverage_norm=coverage_norm, participation=part)
+        with obs.span("engine.aggregate"):
+            if participation is None:
+                weights = jnp.asarray(sizes, jnp.float32)
+                part = None
+            else:
+                weights = jnp.asarray(
+                    np.asarray(participation.weights, np.float32))
+                part = jnp.asarray(
+                    np.asarray(participation.valid, np.float32))
+            if sh is not None:
+                new_params = aggregate_apply_hierarchical(
+                    params, res.deltas, covs, weights, mesh=sh.mesh,
+                    coverage_norm=coverage_norm, participation=part)
+            else:
+                new_params = aggregate_apply(
+                    params, res.deltas, covs, weights,
+                    coverage_norm=coverage_norm, participation=part)
         return new_params, [float(a) for a in res.accs], res.n_steps
 
     def eval_cohort(self, params_stacked, specs: Sequence,

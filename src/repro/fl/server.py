@@ -24,6 +24,7 @@ from typing import Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
+from repro import obs
 from repro.core.elastic import ElasticFamily, family_for
 from repro.core.fairness import accuracy_fairness, round_time_fairness
 from repro.core.latency import LatencyTable
@@ -295,11 +296,12 @@ class CFLServer:
         clients = self.clients if client_ids is None \
             else [self.clients[int(i)] for i in client_ids]
         times = []
-        for client, spec, n in zip(clients, specs, n_steps):
-            prof = self.latency.fleet[client.device]
-            t = n * self.latency.lookup(spec, client.device) + \
-                prof.comm_latency(2 * self.family.param_bytes(spec))
-            times.append(float(t))
+        with obs.span("cfl.bookkeep"):
+            for client, spec, n in zip(clients, specs, n_steps):
+                prof = self.latency.fleet[client.device]
+                t = n * self.latency.lookup(spec, client.device) + \
+                    prof.comm_latency(2 * self.family.param_bytes(spec))
+                times.append(float(t))
         return times
 
     def cohort_specs(self, participants: Optional[Sequence[int]] = None
@@ -313,22 +315,32 @@ class CFLServer:
         """Runtime hook, called once per applied server step: the
         search-helper update (Alg. 2) over the deltas that were just
         aggregated — participants only: absentees reported nothing."""
-        self.predictor.add_profiles(
-            [(spec, self.clients[i].quality, acc)
-             for spec, i, acc in zip(specs, participants, accs)])
-        mae = self.predictor.train_round(epochs=4)
-        return {"specs": [self.family.genes(s) for s in specs],
-                "predictor_mae": mae}
+        with obs.span("cfl.post_aggregate"):
+            with obs.span("predictor.add"):
+                self.predictor.add_profiles(
+                    [(spec, self.clients[i].quality, acc)
+                     for spec, i, acc in zip(specs, participants, accs)])
+            with obs.span("predictor.train"):
+                mae = self.predictor.train_round(epochs=4)
+            return {"specs": [self.family.genes(s) for s in specs],
+                    "predictor_mae": mae}
 
     def run_round(self) -> Dict:
         if getattr(self.fl, "mode", "sync") == "async":
             return self.runtime.run_until_aggregate()
-        sel = self.tracker.select(self.round_idx)
-        participants = [int(i) for i in sel.participants]
-        specs = self.sample_submodels(
-            None if self.tracker.is_full else participants)
+        with obs.span("cfl.round", round=self.round_idx):
+            return self._sync_round()
+
+    def _sync_round(self) -> Dict:
+        with obs.span("cfl.select"):
+            sel = self.tracker.select(self.round_idx)
+            participants = [int(i) for i in sel.participants]
+        with obs.span("cfl.search"):
+            specs = self.sample_submodels(
+                None if self.tracker.is_full else participants)
         stats = None
-        if getattr(self.fl, "faults", None) is not None:
+        faulty = getattr(self.fl, "faults", None) is not None
+        if faulty:
             from repro.fl.faults import faulty_sync_round
             accs, times, participants, specs_kept, stats = \
                 faulty_sync_round(self, specs, sel)
@@ -340,23 +352,24 @@ class CFLServer:
             else:
                 accs, times = self._train_round_sequential(specs, sel)
             extras = self.post_aggregate(specs, participants, accs)
-            self.tracker.record(participants, accs)
-
-        rec = {
-            "round": self.round_idx,
-            "participants": participants,
-            "selection": self.tracker.policy.name,
-            "accs": accs,
-            "fairness": accuracy_fairness(accs if accs
-                                          else [float("nan")]),
-            "timing": round_time_fairness(times if times else [0.0]),
-        }
-        rec.update(extras)
-        rec.update(self._sync_clock_columns(times))
-        if stats is not None:
-            rec.update(stats)
-        self.history.append(rec)
-        self.round_idx += 1
+        with obs.span("cfl.bookkeep"):
+            if not faulty:
+                self.tracker.record(participants, accs)
+            rec = {
+                "round": self.round_idx,
+                "participants": participants,
+                "selection": self.tracker.policy.name,
+                "accs": accs,
+                "fairness": accuracy_fairness(accs if accs
+                                              else [float("nan")]),
+                "timing": round_time_fairness(times if times else [0.0]),
+            }
+            rec.update(extras)
+            rec.update(self._sync_clock_columns(times))
+            if stats is not None:
+                rec.update(stats)
+            self.history.append(rec)
+            self.round_idx += 1
         return rec
 
     def _sync_clock_columns(self, times: Sequence[float]) -> Dict:

@@ -24,12 +24,14 @@ indices, positions), never shapes — so churn never recompiles.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models import transformer as T
 from repro.serving.batcher import Completion, ContinuousBatcher, Request
 
@@ -81,6 +83,8 @@ class EdgeServer:
         self._slot_masks: List[Any] = [full_fwd] * slots
         self._slot_pos = np.zeros((slots,), np.int32)
         self._slot_tok = np.zeros((slots,), np.int32)
+        # submit times by uid, kept while spans record (``repro.obs``)
+        self._submitted: Dict[Any, int] = {}
 
         cfg, kern, cdt = self.cfg, self._kernels, cache_dtype
 
@@ -128,20 +132,31 @@ class EdgeServer:
             sub, jnp.asarray(logits) / self.temperature))
 
     def _admit_one(self, slot: int, req: Request) -> Optional[Completion]:
-        toks = self._fit_prompt(req.prompt)
-        spec = req.spec if req.spec is not None else self.family.full_spec()
-        host_fwd = self._host_masks(spec)
-        fwd = jax.tree.map(jnp.asarray, host_fwd)
-        logits, slot_caches = self._prefill_fn(self.params, toks[None], fwd)
-        self._caches = self._write_fn(self._caches, slot_caches,
-                                      jnp.int32(slot))
-        self._slot_masks[slot] = host_fwd
-        self._slot_pos[slot] = self.prompt_len
-        logits0 = np.asarray(logits[0])
-        tok = self._sample(logits0)
-        self._slot_tok[slot] = tok
-        return self.batcher.record(
-            slot, tok, logits0 if self.trace_logits else None)
+        submitted = self._submitted.pop(req.uid, None)
+        if submitted is not None:
+            obs.record("serve.queue", submitted, time.perf_counter_ns(),
+                       uid=req.uid)
+        with obs.span("serve.admit", uid=req.uid):
+            toks = self._fit_prompt(req.prompt)
+            spec = req.spec if req.spec is not None \
+                else self.family.full_spec()
+            with obs.span("serve.masks"):
+                host_fwd = self._host_masks(spec)
+                fwd = jax.tree.map(jnp.asarray, host_fwd)
+            with obs.span("serve.prefill"):
+                logits, slot_caches = self._prefill_fn(self.params,
+                                                       toks[None], fwd)
+            with obs.span("serve.write"):
+                self._caches = self._write_fn(self._caches, slot_caches,
+                                              jnp.int32(slot))
+            self._slot_masks[slot] = host_fwd
+            self._slot_pos[slot] = self.prompt_len
+            with obs.span("serve.first_token_wait"):
+                logits0 = np.asarray(logits[0])
+                tok = self._sample(logits0)
+            self._slot_tok[slot] = tok
+            return self.batcher.record(
+                slot, tok, logits0 if self.trace_logits else None)
 
     # -- public API --------------------------------------------------------
     def submit(self, request: Request) -> None:
@@ -150,32 +165,46 @@ class EdgeServer:
             # longer generations would decode past the allocated positions
             request = dataclasses.replace(
                 request, max_new_tokens=self.max_new_tokens)
+        if obs.active():
+            self._submitted[request.uid] = time.perf_counter_ns()
         self.batcher.submit(request)
 
     def step(self) -> List[Completion]:
         """One scheduler tick: admit queued requests into free slots
         (prefill + first token), then run one batched decode step for all
         occupied slots. Returns completions finished this tick."""
+        admitted = self.batcher.admit()
+        with obs.span("serve.step", admitted=len(admitted),
+                      active=len(self.batcher.occupied())):
+            return self._step(admitted)
+
+    def _step(self, admitted: List[int]) -> List[Completion]:
         done: List[Completion] = []
-        for slot in self.batcher.admit():
+        for slot in admitted:
             c = self._admit_one(slot, self.batcher.request_at(slot))
             if c is not None:
                 done.append(c)
         active = self.batcher.occupied()
         if not active:
             return done
-        logits_all, self._caches = self._step_fn(
-            self.params, self._caches, jnp.asarray(self._slot_tok),
-            jnp.asarray(self._slot_pos), self._stacked_masks())
-        logits_np = np.asarray(logits_all)
-        for slot in active:
-            self._slot_pos[slot] += 1
-            tok = self._sample(logits_np[slot])
-            self._slot_tok[slot] = tok
-            c = self.batcher.record(
-                slot, tok, logits_np[slot] if self.trace_logits else None)
-            if c is not None:
-                done.append(c)
+        with obs.span("serve.stack_masks"):
+            fwd = self._stacked_masks()
+        with obs.span("serve.decode_dispatch"):
+            logits_all, self._caches = self._step_fn(
+                self.params, self._caches, jnp.asarray(self._slot_tok),
+                jnp.asarray(self._slot_pos), fwd)
+        with obs.span("serve.logits_wait"):
+            logits_np = np.asarray(logits_all)
+        with obs.span("serve.sample"):
+            for slot in active:
+                self._slot_pos[slot] += 1
+                tok = self._sample(logits_np[slot])
+                self._slot_tok[slot] = tok
+                c = self.batcher.record(
+                    slot, tok,
+                    logits_np[slot] if self.trace_logits else None)
+                if c is not None:
+                    done.append(c)
         return done
 
     def run(self, requests: Sequence[Request]) -> List[Completion]:
