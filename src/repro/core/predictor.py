@@ -14,7 +14,7 @@ the predictor itself only appends the data-quality one-hot.
 """
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +48,7 @@ class AccuracyPredictor:
         self.family: ElasticFamily = family_for(cfg)
         self.cfg = self.family.cfg
         d = feature_dim(self.family)
+        self._structure: Dict[Tuple, np.ndarray] = {}
         key = jax.random.PRNGKey(seed)
         ks = jax.random.split(key, 4)
         dims = [d, hidden, hidden, hidden, 1]
@@ -84,11 +85,28 @@ class AccuracyPredictor:
             return apply_updates(params, upd), opt_state, l
         self._train_step = train_step
 
+    def _features(self, specs: Sequence, qualities: Sequence[int],
+                  n_rows: int) -> np.ndarray:
+        """``featurize`` rows of (spec, quality), zero rows below them up
+        to ``n_rows``; a spec's structure features are memoised by its
+        genes."""
+        nf = self.family.feature_dim
+        x = np.zeros((n_rows, nf + N_QUALITY_LEVELS), np.float32)
+        for i, (spec, q) in enumerate(zip(specs, qualities)):
+            key = self.family.genes(spec)
+            f = self._structure.get(key)
+            if f is None:
+                f = self._structure[key] = np.asarray(
+                    self.family.featurize(spec), np.float32)
+            x[i, :nf] = f
+            x[i, nf + int(q)] = 1.0
+        return x
+
     # -- Alg. 2 ------------------------------------------------------------
     def add_profiles(self, samples: Sequence[Tuple]):
         """samples: (spec, quality_level, observed_accuracy)."""
         for spec, q, acc in samples:
-            self.buffer_x.append(featurize(self.family, spec, q))
+            self.buffer_x.append(self._features([spec], [q], 1)[0])
             self.buffer_y.append(float(acc))
 
     def train_round(self, epochs: int = 1):
@@ -112,8 +130,17 @@ class AccuracyPredictor:
         x = jnp.asarray(featurize(self.family, spec, quality))[None]
         return float(self._net(self.params, x)[0])
 
+    def predict_rows(self, specs: Sequence, qualities: Sequence[int], *,
+                     pad_to: Optional[int] = None) -> np.ndarray:
+        """Scores of the rows (specs[i], qualities[i]) in one device call.
+        Rows are padded to ``pad_to`` (a caller's fixed batch, so varying
+        row counts share one compiled shape) and the padding is dropped."""
+        n = len(specs)
+        x = self._features(specs, qualities, max(n, pad_to or 0))
+        with obs.span("search.predict"):
+            obs.count("search.predict_calls")
+            obs.count("search.predict_rows", n)
+            return np.asarray(self._net(self.params, jnp.asarray(x)))[:n]
+
     def predict_batch(self, specs: Sequence, quality: int) -> np.ndarray:
-        obs.count("search.predict_calls")
-        x = jnp.asarray(np.stack([featurize(self.family, s, quality)
-                                  for s in specs]))
-        return np.asarray(self._net(self.params, x))
+        return self.predict_rows(specs, [quality] * len(specs))

@@ -5,7 +5,8 @@ Submodels are generated by a genetic algorithm in the family's spec space
 (d_ff, experts, SSD heads, depth-gate) genes), then filtered through the
 search helper = online accuracy predictor (Alg. 2) + offline latency LUT.
 Per worker k we keep the submodel maximising predicted accuracy subject
-to ``g(ω, p_k) < l_k``.
+to ``g(ω, p_k) < l_k``. All workers' GAs run in lockstep, so each
+generation scores the whole cohort's candidates in one predictor call.
 
 Family-agnostic: mutation, crossover, and random sampling are the
 ``ElasticFamily`` spec-space surface; this module never touches a
@@ -46,38 +47,8 @@ def search_submodel(cfg, predictor: AccuracyPredictor,
                     seed: int = 0):
     """GA for one worker (Alg. 1 inner loop); ``cfg`` may be any family
     config or an ElasticFamily instance."""
-    fam = family_for(cfg)
-    rng = random.Random(seed)
-    pop = [fam.random_spec(rng) for _ in range(search_cfg.population)]
-    best = None
-    best_acc = -1.0
-
-    for _ in range(search_cfg.generations):
-        feasible = [s for s in pop
-                    if latency.lookup(s, device) < latency_bound]
-        if feasible:
-            accs = predictor.predict_batch(feasible, quality)
-            order = np.argsort(-accs)
-            if accs[order[0]] > best_acc:
-                best_acc = float(accs[order[0]])
-                best = feasible[order[0]]
-            elites = [feasible[i] for i in order[:search_cfg.elite]]
-        else:
-            elites = []
-        nxt = list(elites)
-        while len(nxt) < search_cfg.population:
-            if elites and rng.random() < search_cfg.crossover_prob:
-                child = fam.crossover(rng.choice(elites), rng.choice(pop),
-                                      rng)
-            else:
-                child = fam.random_spec(rng)
-            nxt.append(fam.mutate(child, rng, search_cfg.mutate_prob))
-        pop = nxt
-
-    if best is None:
-        # nothing feasible: fall back to the smallest submodel
-        best = fam.minimal_spec()
-    return best
+    return _lockstep(family_for(cfg), predictor, latency, [device],
+                     [quality], [latency_bound], [seed], search_cfg)[0]
 
 
 def search_all_workers(cfg, predictor: AccuracyPredictor,
@@ -87,12 +58,63 @@ def search_all_workers(cfg, predictor: AccuracyPredictor,
                        search_cfg: SearchConfig = SearchConfig(),
                        seed: int = 0) -> List:
     """Alg. 1 outer loop: one submodel per worker."""
-    fam = family_for(cfg)
-    out = []
-    for k, (d, q, lb) in enumerate(zip(devices, qualities, latency_bounds)):
-        with obs.span("cfl.search.worker"):
-            out.append(search_submodel(
-                fam, predictor, latency, device=d, quality=q,
-                latency_bound=lb, search_cfg=search_cfg,
-                seed=seed * 977 + k))
-    return out
+    seeds = [seed * 977 + k for k in range(len(devices))]
+    return _lockstep(family_for(cfg), predictor, latency, devices,
+                     qualities, latency_bounds, seeds, search_cfg)
+
+
+def _lockstep(fam, predictor: AccuracyPredictor, latency: LatencyTable,
+              devices: Sequence[str], qualities: Sequence[int],
+              latency_bounds: Sequence[float], seeds: Sequence[int],
+              search_cfg: SearchConfig) -> List:
+    """K workers' GAs advanced one generation at a time.
+
+    Each worker keeps its own RNG, population, elites and best; only the
+    scoring is shared: every worker's feasible candidates of a generation
+    are scored in one predictor call, padded to K x population rows so a
+    cohort size compiles one shape. The predictor draws nothing from the
+    RNGs, so each worker makes the same draws, in the same order, as a GA
+    run on its own, and keeps the same best."""
+    n_pop = search_cfg.population
+    rngs = [random.Random(s) for s in seeds]
+    pops = [[fam.random_spec(rng) for _ in range(n_pop)] for rng in rngs]
+    best: List = [None] * len(rngs)
+    best_acc = [-1.0] * len(rngs)
+
+    for gen in range(search_cfg.generations):
+        with obs.span("cfl.search.generation"):
+            feasible = [[s for s in pop if latency.lookup(s, d) < lb]
+                        for pop, d, lb in zip(pops, devices,
+                                              latency_bounds)]
+            rows = [s for f in feasible for s in f]
+            if rows:
+                scores = predictor.predict_rows(
+                    rows, [q for f, q in zip(feasible, qualities)
+                           for _ in f], pad_to=len(rngs) * n_pop)
+            at = 0
+            for k, (rng, pop, feas) in enumerate(zip(rngs, pops, feasible)):
+                if feas:
+                    accs = scores[at:at + len(feas)]
+                    at += len(feas)
+                    order = np.argsort(-accs)
+                    if accs[order[0]] > best_acc[k]:
+                        best_acc[k] = float(accs[order[0]])
+                        best[k] = feas[order[0]]
+                    elites = [feas[i] for i in order[:search_cfg.elite]]
+                else:
+                    elites = []
+                if gen == search_cfg.generations - 1:
+                    continue        # a last brood would never be scored
+                nxt = list(elites)
+                while len(nxt) < n_pop:
+                    if elites and rng.random() < search_cfg.crossover_prob:
+                        child = fam.crossover(rng.choice(elites),
+                                              rng.choice(pop), rng)
+                    else:
+                        child = fam.random_spec(rng)
+                    nxt.append(fam.mutate(child, rng,
+                                          search_cfg.mutate_prob))
+                pops[k] = nxt
+
+    # nothing feasible: fall back to the smallest submodel
+    return [fam.minimal_spec() if b is None else b for b in best]

@@ -15,6 +15,7 @@ from repro import obs
 from repro.configs import ARCHS, reduced
 from repro.configs.paper_cnn import CNNConfig
 from repro.core.elastic import family_for
+from repro.core.search import SearchConfig
 from repro.core.latency import EDGE_FLEET
 from repro.fl import CFLConfig, CFLSession
 from repro.fl.client import ClientInfo
@@ -209,19 +210,19 @@ def _session(population):
 @pytest.fixture(scope="module")
 def cfl_runs():
     """The same two rounds with spans off and on: (params, history,
-    records, increments, predict_batch calls) of each."""
+    records, increments, predict_rows calls) of each."""
     out, population = {}, _population()
     for on in (False, True):
         obs.reset()
         sess = _session(population)
         pred = sess.server.predictor
         calls = []
-        inner = pred.predict_batch
+        inner = pred.predict_rows
 
         def counted(*a, **k):
             calls.append(1)
             return inner(*a, **k)
-        pred.predict_batch = counted
+        pred.predict_rows = counted
         if on:
             obs.enable()
         sess.run(2)
@@ -251,14 +252,22 @@ def test_cfl_round_spans_and_predict_counter(cfl_runs):
     post = [i for i, r in enumerate(recs) if r.name == "cfl.post_aggregate"]
     for i in post:
         assert _children(recs, i) == ["predictor.add", "predictor.train"]
-    # round 0 draws random specs; round 1 runs one GA per worker
-    workers = [i for i, r in enumerate(recs) if r.name == "cfl.search.worker"]
-    assert len(workers) == 2
+    # round 0 draws random specs; round 1 runs the workers' GAs in
+    # lockstep, one span per generation
+    gens = [i for i, r in enumerate(recs)
+            if r.name == "cfl.search.generation"]
+    assert len(gens) == SearchConfig().generations
     assert all(_ancestors(recs, i)[:2] == ["cfl.search", "cfl.round"]
-               for i in workers)
+               for i in gens)
+    assert {recs[recs[recs[i].parent].parent].attrs["round"]
+            for i in gens} == {1}
     pcalls = [x for x in incs if x.name == "search.predict_calls"]
     assert calls > 0 and sum(x.n for x in pcalls) == calls
-    assert all(recs[x.parent].name == "cfl.search.worker" for x in pcalls)
+    assert all(recs[x.parent].name == "search.predict" for x in pcalls)
+    assert all(_ancestors(recs, x.parent)[0] == "cfl.search.generation"
+               for x in pcalls)
+    rows = [x for x in incs if x.name == "search.predict_rows"]
+    assert len(rows) == calls and all(x.n >= 1 for x in rows)
     assert all(r.end_ns is not None for r in recs)
 
 
