@@ -45,7 +45,7 @@ def main(argv=None) -> int:
     spans = Spans()
     server, specs, prog_specs = D.build(cell, args.seed, spans)
     spec_of = D.spec_index(specs)
-    vocab = cell.config["model"]["vocab_size"]
+    vocab = cell.arch().vocab_size(cell)
     for i, rate in enumerate(args.rates):
         mix = dict(cell.traffic, rate=rate)
         ledger = D.Ledger()

@@ -1,10 +1,13 @@
 """Driver for serving traffic: ``EdgeServer.submit`` / ``step`` from the
 public API.
 
-Set-up builds the parent weights from the seed in one jitted call, the
-tenants' submodel masks, and one ``EdgeServer``; a warm batch of requests
-compiles its prefill, cache-write and decode-step programs. The window
-then offers the mix's load:
+The model half comes from the file that the configuration's ``"arch"``
+key names (``archs/<arch>.py``): the system's family, the parent weights
+from the seed in one jitted call, the tenants' submodels, the vocabulary
+and the plain reference. Set-up builds those, the submodel masks and one
+``EdgeServer``; a warm batch of requests compiles its prefill,
+cache-write and decode-step programs. The window then offers the mix's
+load:
 
 * ``arrival: poisson`` — an open loop at the mix's fixed rate for the
   window; every request due in it is served to its end (at most
@@ -16,9 +19,9 @@ Tokens are timed when ``step()`` returns: a slot newly occupied got its
 first token (from the prefill) and the step's decode token; a slot that
 was already occupied got one more token.
 
-``correct`` runs the plain reference (``reference/granite_ref.py``) over
-a seeded sample of finished requests, the longest among them, once the
-server is freed.
+``correct`` runs the model file's plain reference over a seeded sample
+of finished requests, the longest among them, once the server is
+freed.
 """
 from __future__ import annotations
 
@@ -35,31 +38,6 @@ from chipbench.harness import device, requests, seeds
 from chipbench.harness.compile_meter import CompileMeter
 from chipbench.harness.result import Check, Run, log
 from chipbench.harness.spans import Spans
-from chipbench.reference import granite_ref
-
-WIDTH_KEYS = {"hidden_size": "d_model", "num_attention_heads": "n_heads",
-              "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim",
-              "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-              "num_hidden_layers": "n_layers", "rope_theta": "rope_theta",
-              "rms_norm_eps": "norm_eps"}
-
-
-def program_config(cell):
-    """The system's own model config, cut to the file's depth; every width
-    must equal the configuration file's."""
-    from repro.configs.archs import ARCHS
-    from repro.configs.base import depth_cut
-    c = cell.config["model"]
-    pcfg = depth_cut(ARCHS[cell.config["system_arch"]],
-                     c["num_hidden_layers"])
-    for ours, theirs in WIDTH_KEYS.items():
-        if getattr(pcfg, theirs) != c[ours]:
-            raise ValueError(f"{cell.config['name']}: {ours} is {c[ours]} "
-                             f"in the configuration, "
-                             f"{getattr(pcfg, theirs)} in the system")
-    if not pcfg.tie_embeddings or pcfg.act != "silu" or not pcfg.mlp_gated:
-        raise ValueError("the system's model is not the configured one")
-    return pcfg
 
 
 class Ledger:
@@ -107,29 +85,23 @@ class Ledger:
 def build(cell, seed: int, spans: Spans):
     """Set-up: weights from the seed, the tenants' submodels, one
     EdgeServer, and a warm batch through its three programs."""
-    from repro.core.elastic import TransformerElasticFamily
-    from repro.core.submodel import TransformerSubSpec
     from repro.serving.batcher import Request
     from repro.serving.server import EdgeServer
 
     from chipbench.harness.bench import apply_precision
     apply_precision(cell.config)
-    c, mix = cell.config["model"], cell.traffic
-    family = TransformerElasticFamily(program_config(cell))
-    key = seeds.jax_key(seed, 4)
-    params = jax.jit(lambda k: granite_ref.init_params(k, c))(key)
-    want = jax.eval_shape(family.init_params, key)
+    arch, mix = cell.arch(), cell.traffic
+    family = arch.family(cell)
+    params = arch.init_params(cell, seed)
+    # shapes only: the key's value does not matter
+    want = jax.eval_shape(family.init_params, jax.random.PRNGKey(0))
     if jax.tree.structure(want) != jax.tree.structure(params) or any(
             a.shape != b.shape for a, b in zip(jax.tree.leaves(want),
                                                jax.tree.leaves(params))):
         raise ValueError("the reference's weight layout is not the "
                          "system's")
-    specs = requests.tenant_specs(mix, seed, c["num_hidden_layers"],
-                                  cell.config["elastic_widths"])
-    prog_specs = [TransformerSubSpec(layers=(s["layers"],),
-                                     ff_frac=s["ff_frac"],
-                                     attn_head_frac=s["head_frac"])
-                  for s in specs]
+    specs = arch.tenant_specs(cell, seed)
+    prog_specs = [arch.program_spec(s) for s in specs]
     with spans.span("setup.masks"):
         for s in prog_specs:
             family.decode_masks(s)
@@ -139,7 +111,7 @@ def build(cell, seed: int, spans: Spans):
     wrng = seeds.stream(seed, 21)
     for i in range(mix["slots"]):
         server.submit(Request(uid=-1 - i, spec=prog_specs[i % len(specs)],
-                              prompt=wrng.integers(0, c["vocab_size"],
+                              prompt=wrng.integers(0, arch.vocab_size(cell),
                                                    mix["prompt_len"],
                                                    dtype=np.int32),
                               max_new_tokens=3))
@@ -154,7 +126,7 @@ def spec_index(specs):
 
 
 def run(cell, run: Run, ctx: Dict) -> None:
-    c, mix = cell.config["model"], cell.traffic
+    mix, vocab = cell.traffic, cell.arch().vocab_size(cell)
     meter, spans = CompileMeter(), Spans()
     run.spans = spans
     server, specs, prog_specs = build(cell, run.seed, spans)
@@ -175,12 +147,12 @@ def run(cell, run: Run, ctx: Dict) -> None:
     with spans.span("window"):
         if mix["arrival"] == "poisson":
             window_tokens = _open_loop(server, ledger, spans, mix, run.seed,
-                                       seconds, c["vocab_size"],
+                                       seconds, vocab,
                                        prog_specs, spec_of, t0)
             t1 = t0 + seconds
         else:
             window_tokens, t1 = _backlog(server, ledger, spans, mix,
-                                         run.seed, seconds, c["vocab_size"],
+                                         run.seed, seconds, vocab,
                                          prog_specs, spec_of, t0)
     gc.callbacks.remove(pauses)
     if run.traced:
@@ -193,7 +165,7 @@ def run(cell, run: Run, ctx: Dict) -> None:
                          "slots": mix["slots"]})
     run.steps = ledger.steps
     run.extra = {"ledger": ledger, "specs": specs, "spec_of": spec_of,
-                 "config": c, "mix": mix}
+                 "cell": cell, "mix": mix}
     _end_to_end(run, ledger, mix, window_tokens)
     run.memory_peak_bytes = device.memory_peak_bytes(ctx["devices"])
     log(f"programs {progs} -> {server.compiled_programs()}; compiles in "
@@ -353,31 +325,18 @@ def served_prompt(prompt: np.ndarray, window: int) -> np.ndarray:
 
 def reference_gaps(cell, seed: int, sample: List, specs: List, spec_of,
                    ledger: Ledger, *, control=None):
-    """Widest gap of the served tokens; with ``control`` (dtype, matmul
-    precision) also the widest gap of the tokens that the reference
-    computed that way puts first at the same positions."""
-    c, mix = cell.config["model"], cell.traffic
-    params = jax.jit(lambda k: granite_ref.init_params(k, c))(
-        seeds.jax_key(seed, 4))
-    ref_fn = granite_ref.make_logits(c)
-    ctl = None
-    if control is not None:
-        dtype, prec = control
-        ctl = (ref_fn, jax.tree.map(lambda a: a.astype(dtype), params), prec)
-    length = mix["prompt_len"] + mix["max_new_tokens"] - 1
-    widest, widest_ctl, n = 0.0, 0.0, 0
-    for comp in sample:
-        s = specs[spec_of(ledger.req[comp.uid]["draw"])]
-        masks = granite_ref.tenant_masks(c, s["layers"], s["ff_frac"],
-                                         s["head_frac"])
-        g, gc_ = granite_ref.served_gaps(
-            ref_fn, params, served_prompt(comp.prompt, mix["prompt_len"]),
-            np.asarray(comp.tokens, np.int32), masks, length, control=ctl)
-        widest = max(widest, float(g.max()))
-        if gc_ is not None:
-            widest_ctl = max(widest_ctl, float(gc_.max()))
-        n += len(g)
-    return widest, widest_ctl, n
+    """Widest gap of the served tokens, by the model file's reference;
+    with ``control`` (dtype, matmul precision) also the widest gap of the
+    tokens that the reference computed that way puts first at the same
+    positions. Each request is handed over as the server saw it: its
+    prompt in the server's window, its tokens and its submodel."""
+    mix = cell.traffic
+    served = [(served_prompt(comp.prompt, mix["prompt_len"]), comp.tokens,
+               specs[spec_of(ledger.req[comp.uid]["draw"])])
+              for comp in sample]
+    return cell.arch().reference_gaps(
+        cell, seed, served, mix["prompt_len"] + mix["max_new_tokens"] - 1,
+        control=control)
 
 
 def compare(cell, seed: int, ledger: Ledger, specs, spec_of) -> List[Check]:

@@ -8,6 +8,10 @@ metric or cell lives in a file of its own, found by name:
 * ``drivers/<driver>.py``    — the code that runs one kind of traffic
 * ``metrics/<metric>.py``    — ``compute(run)`` for one per-layer metric
 * ``limits/<cell>.json``     — the limits the cell's correctness check uses
+* ``archs/<arch>.py``        — the model half of a serving configuration,
+  named by its ``"arch"`` key: the system's config and family, weights
+  and tenants' submodels from the seed, the plain reference's check and
+  the work counts (``archs/gqa_decoder.py`` lists the functions)
 """
 from __future__ import annotations
 
@@ -47,11 +51,29 @@ class Cell:
     limits: Dict
     end_to_end: List[Dict]
     per_layer: List[Dict]
+    _arch: object = dataclasses.field(default=None, repr=False,
+                                      compare=False)
 
     def driver(self):
         kind = self.traffic["driver"]
         return load_module(os.path.join(self.base, "drivers", f"{kind}.py"),
                            f"chipbench_driver_{kind}")
+
+    def arch(self):
+        """The model file ``archs/<arch>.py`` that the configuration's
+        ``"arch"`` key names, loaded once per cell (every caller of one
+        cell then shares it, and a test may replace its functions)."""
+        if self._arch is None:
+            name = self.config.get("arch")
+            if name is None:
+                raise KeyError(
+                    f"configuration {self.config.get('name')!r} names no "
+                    f"\"arch\": a serving configuration names its model "
+                    f"file archs/<arch>.py")
+            self._arch = load_module(
+                os.path.join(self.base, "archs", f"{name}.py"),
+                f"chipbench_arch_{name}")
+        return self._arch
 
 
 def _applies(metric: Dict, cell: str) -> bool:

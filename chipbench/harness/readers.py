@@ -3,8 +3,6 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional
 
-from chipbench.harness import flops
-
 
 def idle_share(run) -> Optional[float]:
     """Percent of the traced window in which no operation ran on the
@@ -41,20 +39,22 @@ def window_steps(run) -> List[Dict]:
 
 
 def step_work(run, step: Dict):
-    """(required FLOPs, required bytes) of one step's batched decode."""
+    """(required FLOPs, required bytes) of one step's batched decode, by
+    the counts of the cell's model file (``archs/<arch>.py``)."""
     ex = run.extra
-    c, specs, spec_of = ex["config"], ex["specs"], ex["spec_of"]
-    req = ex["ledger"].req
+    cell, specs, spec_of = ex["cell"], ex["specs"], ex["spec_of"]
+    arch, req = cell.arch(), ex["ledger"].req
     ss = [specs[spec_of(req[u]["draw"])] for u, _ in step["decoded"]]
     pos = [p for _, p in step["decoded"]]
-    f = sum(flops.token_flops(c, s, p) for s, p in zip(ss, pos))
-    return f, flops.decode_step_bytes(c, ss, pos)
+    f = sum(arch.token_flops(cell, s, p) for s, p in zip(ss, pos))
+    return f, arch.decode_step_bytes(cell, ss, pos)
 
 
 def prefill_work(run, step: Dict) -> float:
+    """Required FLOPs of the prompts one step admitted."""
     ex = run.extra
-    c, specs, spec_of = ex["config"], ex["specs"], ex["spec_of"]
-    req = ex["ledger"].req
-    return sum(flops.prompt_flops(c, specs[spec_of(req[u]["draw"])],
-                                  req[u]["draw"].prompt_len)
+    cell, specs, spec_of = ex["cell"], ex["specs"], ex["spec_of"]
+    arch, req = cell.arch(), ex["ledger"].req
+    return sum(arch.prompt_flops(cell, specs[spec_of(req[u]["draw"])],
+                                 req[u]["draw"].prompt_len)
                for u in step["admitted"])

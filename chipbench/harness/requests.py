@@ -96,20 +96,3 @@ def _draws(mix: Dict, sched, rng, due: np.ndarray, vocab: int,
                  prompt=rng.integers(0, vocab, int(plen[i]),
                                      dtype=np.int32))
             for i in range(n)]
-
-
-def tenant_specs(mix: Dict, seed: int, n_layers: int,
-                 widths: List[float]) -> List[Dict]:
-    """The distinct submodels the tenants use, and which each tenant has:
-    ``mix['specs']`` submodels (kept layers, MLP and head fractions)
-    drawn from the seed, tenant t using submodel t mod specs."""
-    rng = seeds.stream(seed, 13)
-    out = []
-    for _ in range(int(mix["specs"])):
-        k = int(rng.integers(1, n_layers + 1))
-        layers = tuple(sorted(rng.choice(n_layers, k, replace=False)
-                              .tolist()))
-        out.append({"layers": layers,
-                    "ff_frac": float(rng.choice(widths)),
-                    "head_frac": float(rng.choice(widths))})
-    return out

@@ -53,8 +53,7 @@ def drive(cell, seed=77, seconds=2.0):
     run = Run(cell=cell.name, seed=seed, seconds=seconds, traced=False)
     ctx = {"devices": jax.devices()[:1], "process_start": time.time(),
            "trace_dir": None}
-    drv = cell.driver()
     if cell.traffic["driver"] == "serve":
-        drv.program_config = small_system_config
-    drv.run(cell, run, ctx)
+        cell.arch().program_config = small_system_config
+    cell.driver().run(cell, run, ctx)
     return run

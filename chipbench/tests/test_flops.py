@@ -1,11 +1,13 @@
 """The benchmark's FLOP and byte arithmetic against the system's own
-analytic counts (``ElasticFamily.flops``, ``ModelConfig.param_count``)."""
+analytic counts (``ElasticFamily.flops``, ``ModelConfig.param_count``):
+the CNN's in ``harness/flops.py``, granite's in its model file
+(``archs/gqa_decoder.py``)."""
 import json
 import os
 
 import pytest
 
-from chipbench.harness import flops
+from chipbench.harness import bench, flops
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -55,33 +57,38 @@ def _granite():
     return depth_cut(ARCHS["granite-3-8b"], 2)
 
 
+@pytest.fixture(scope="module")
+def cell():
+    return bench.load_cell("granite-3-8b-l2.chat")
+
+
 FULL = {"layers": (0, 1), "ff_frac": 1.0, "head_frac": 1.0}
 
 
-def test_token_flops_full_spec_is_twice_the_matmul_params():
-    c = _config("granite-3-8b-l2")["model"]
+def test_token_flops_full_spec_is_twice_the_matmul_params(cell):
     pcfg = _granite()
     norms = 2 * pcfg.d_model * pcfg.n_layers + pcfg.d_model
-    assert flops.token_flops(c, FULL, 0) == \
+    assert cell.arch().token_flops(cell, FULL, 0) == \
         2.0 * (pcfg.param_count() - norms)
 
 
-def test_token_flops_attention_term_and_submodels():
-    c = _config("granite-3-8b-l2")["model"]
-    per_pos = flops.token_flops(c, FULL, 1) - flops.token_flops(c, FULL, 0)
+def test_token_flops_attention_term_and_submodels(cell):
+    arch = cell.arch()
+    per_pos = (arch.token_flops(cell, FULL, 1)
+               - arch.token_flops(cell, FULL, 0))
     assert per_pos == 2 * 2 * 32 * 128 * 2          # 2 layers, 32 heads
     small = {"layers": (1,), "ff_frac": 0.25, "head_frac": 0.25}
-    assert flops.token_flops(c, small, 100) < flops.token_flops(c, FULL, 100)
-    assert flops.prompt_flops(c, FULL, 3) == pytest.approx(
-        sum(flops.token_flops(c, FULL, k) for k in (1, 2, 3)))
+    assert arch.token_flops(cell, small, 100) < \
+        arch.token_flops(cell, FULL, 100)
+    assert arch.prompt_flops(cell, FULL, 3) == pytest.approx(
+        sum(arch.token_flops(cell, FULL, k) for k in (1, 2, 3)))
 
 
-def test_decode_bytes_full_spec_reads_every_weight_once():
-    c = _config("granite-3-8b-l2")["model"]
-    pcfg = _granite()
-    assert flops.decode_step_bytes(c, [FULL], [0]) == \
+def test_decode_bytes_full_spec_reads_every_weight_once(cell):
+    arch, pcfg = cell.arch(), _granite()
+    assert arch.decode_step_bytes(cell, [FULL], [0]) == \
         4.0 * pcfg.param_count()
     # a second slot of the same submodel adds only its keys and values
-    two = flops.decode_step_bytes(c, [FULL, FULL], [0, 10])
-    assert two - flops.decode_step_bytes(c, [FULL], [0]) == \
+    two = arch.decode_step_bytes(cell, [FULL, FULL], [0, 10])
+    assert two - arch.decode_step_bytes(cell, [FULL], [0]) == \
         2 * 2 * 8 * 128 * 10 * 4

@@ -191,9 +191,8 @@ def test_small_traced_serving_run(tmp_path):
     run = Run(cell=cell.name, seed=5, seconds=2.0, traced=True)
     ctx = {"devices": jax.devices()[:1], "process_start": time.time(),
            "trace_dir": str(tmp_path / "trace")}
-    drv = cell.driver()
-    drv.program_config = small.small_system_config
-    drv.run(cell, run, ctx)
+    cell.arch().program_config = small.small_system_config
+    cell.driver().run(cell, run, ctx)
     for name in ("step_host_self_ms.chat", "admit_wait_p95_ms.chat",
                  "idle_unattributed_share.chat"):
         value = read(name, run)

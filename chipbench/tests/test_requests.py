@@ -52,10 +52,3 @@ def test_backlog_deterministic_and_endless():
     b = [next(g2) for _ in range(100)]
     assert _key(a) == _key(b)
     assert [d.uid for d in a] == list(range(100))
-
-
-def test_tenant_specs_seeded():
-    w = [0.25, 0.5, 0.75, 1.0]
-    s1 = requests.tenant_specs({"specs": 3}, BIG, 2, w)
-    assert s1 == requests.tenant_specs({"specs": 3}, BIG, 2, w)
-    assert all(1 <= len(s["layers"]) <= 2 for s in s1)
